@@ -28,7 +28,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.configs.shapes import ShapeConfig
-from repro.roofline.analysis import HBM_BW, ICI_BW, PEAK_FLOPS
+from repro.roofline.analysis import TARGET
 
 
 def step_time(cfg: ArchConfig, shape: ShapeConfig, chips: float,
@@ -46,12 +46,12 @@ def step_time(cfg: ArchConfig, shape: ShapeConfig, chips: float,
     budget = hbm_gb * 1e9
     remat = 1.0 if resident < 0.9 * budget else 4.0 / 3.0
 
-    compute = 6.0 * n * tokens * remat / (chips * PEAK_FLOPS)
-    memory = (3.0 * param_bytes + 4.0 * act_bytes) / (chips * HBM_BW)
+    compute = 6.0 * n * tokens * remat / (chips * TARGET.peak_flops)
+    memory = (3.0 * param_bytes + 4.0 * act_bytes) / (chips * TARGET.hbm_bw)
     # FSDP gather + gradient reduce-scatter: every device moves ~the full
     # parameter bytes per step REGARDLESS of chip count (ring collectives)
     # — the strong-scaling wall the provisioner must respect
-    coll = (2.5 * param_bytes / ICI_BW
+    coll = (2.5 * param_bytes / TARGET.ici_bw
             + 2e-3 * math.log2(max(chips, 2)))       # latency floor
     t = max(compute, memory, coll)
     if noise and rng is not None:

@@ -13,6 +13,13 @@ Job functions must be importable ``module:qualname`` callables — a
 closure cannot cross the process boundary, and a launch without an
 importable fn FAILs loudly instead of pretending to run.
 
+A chip belongs to one process at a time. With jobs that run on a chip,
+only the worker may touch JAX on the device: an engine process that has
+initialised a TPU backend holds the chip, and the worker's jobs then fail
+or hang. The detached worker also keeps the chip after the engine exits,
+until it is shut down. One-process callers use the in-process runners
+(``runner="local"``, the default, or ``"thread"``).
+
 Terminal application is epoch-guarded end to end: the worker stamps
 every result with the epoch it was launched under, and ``_apply`` writes
 through ``registry.set_state(expect_epoch=...)`` — a result from a

@@ -25,7 +25,8 @@ import math
 import time
 from typing import Callable, Optional
 
-from repro.roofline.prior import HardwareSpec, roofline_ceiling_s
+from repro.roofline.analysis import HardwareSpec, device_peaks
+from repro.roofline.prior import roofline_ceiling_s
 
 HYSTERESIS = 0.03        # a neighbor must win by >=3% to displace the
                          # incumbent — timing-noise damper + determinism
@@ -291,15 +292,11 @@ def max_abs_err(spec: KernelSpec, args, ref_out, cfg: dict,
 
 
 def default_family() -> str:
-    """The accelerator family tuning runs against; ``interpret`` when no
-    real TPU backend is attached (CI / CPU hosts)."""
-    try:
-        import jax
-        if jax.devices()[0].platform == "tpu":
-            return "tpu"
-    except Exception:  # noqa: BLE001 — jax absent/broken: still hermetic
-        pass
-    return "interpret"
+    """The accelerator family tuning runs against: the device kind JAX
+    reports, or ``interpret`` on a CPU host (Pallas interpret mode)."""
+    import jax
+    dev = jax.devices()[0]
+    return "interpret" if dev.platform == "cpu" else dev.device_kind
 
 
 # interpret-mode "hardware": CPU-interpreter constants so the recorded
@@ -307,14 +304,14 @@ def default_family() -> str:
 # not silicon) without pretending CI timings are TPU timings.
 INTERPRET_HW = HardwareSpec("interpret", peak_flops=50e9, hbm_bw=20e9,
                             ici_bw=1.0)
-FAMILY_HW: dict[str, HardwareSpec] = {"interpret": INTERPRET_HW}
 
 
 def _family_hw(family: str) -> HardwareSpec:
-    if family in FAMILY_HW:
-        return FAMILY_HW[family]
-    from repro.roofline.prior import TPU_V5E
-    return TPU_V5E if family.startswith("tpu") else INTERPRET_HW
+    """Peaks for ``family``; a device kind without published peaks
+    raises."""
+    if family == "interpret":
+        return INTERPRET_HW
+    return device_peaks(family)
 
 
 # -- the tuning cache ----------------------------------------------------

@@ -7,9 +7,11 @@ the (N x P) state carries across the innermost grid dim in VMEM scratch.
 Scalar-per-head decay makes the exponent algebra 1-D (cheaper than WKV6's
 per-channel decay).
 
-Layouts: x (B,H,S,P) blocked (1,1,C,P); dt (B,H,S) blocked (1,1,C);
-Bmat/Cmat (B,G,S,N) blocked (1,1,C,N) with head->group index mapping;
-A,D (H,). Grid (B, H, NC).
+Layouts: x (B,H,S,P) blocked (1,1,C,P); dt (B,H,S) viewed as (B,H,S,1)
+and blocked (1,1,C,1), a column whose last dim is the array's (the TPU's
+8x128 tiling rule); Bmat/Cmat (B,G,S,N) blocked (1,1,C,N) with
+head->group index mapping; A,D (H,) whole in SMEM, read per head.
+Grid (B, H, NC).
 """
 from __future__ import annotations
 
@@ -20,9 +22,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.rwkv6 import chunk_cumsum, dot_f32
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref,
                 state_scr, *, chunk: int):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -31,37 +36,32 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref,
 
     f32 = jnp.float32
     x = x_ref[0, 0].astype(f32)           # (C, P)
-    dt = dt_ref[0, 0].astype(f32)         # (C,)
-    a = a_ref[0].astype(f32)              # scalar <0
+    dt = dt_ref[0, 0].astype(f32)         # (C, 1)
+    a = a_ref[hi].astype(f32)             # scalar <0
     bm = b_ref[0, 0].astype(f32)          # (C, N)
     cm = c_ref[0, 0].astype(f32)          # (C, N)
-    dcoef = d_ref[0].astype(f32)
+    dcoef = d_ref[hi].astype(f32)
 
-    la = dt * a                           # (C,) log decay per token
-    cum = jnp.cumsum(la)                  # inclusive
-    tot = cum[-1]
-    xd = x * dt[:, None]                  # dt-weighted input
+    la = dt * a                           # (C, 1) log decay per token
+    cum = chunk_cumsum(la, chunk)         # inclusive
+    tot = jnp.sum(la)                     # scalar: total chunk decay
+    xd = x * dt                           # dt-weighted input
 
     state = state_scr[...]                # (N, P)
     # inter-chunk: y_t += C_t exp(cum_t) . state
-    cdec = cm * jnp.exp(cum)[:, None]
-    y = jax.lax.dot_general(cdec, state, (((1,), (0,)), ((), ())),
-                            preferred_element_type=f32)
+    cdec = cm * jnp.exp(cum)
+    y = dot_f32(cdec, state, ((1,), (0,)))
     # intra-chunk pairs j <= t (half-shifted exponents)
-    cs = cm * jnp.exp(cum - 0.5 * tot)[:, None]
-    bs = bm * jnp.exp(0.5 * tot - cum)[:, None]
-    att = jax.lax.dot_general(cs, bs, (((1,), (1,)), ((), ())),
-                              preferred_element_type=f32)
+    cs = cm * jnp.exp(cum - 0.5 * tot)
+    bs = bm * jnp.exp(0.5 * tot - cum)
+    att = dot_f32(cs, bs, ((1,), (1,)))
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     att = jnp.where(ii >= jj, att, 0.0)
-    y = y + jax.lax.dot_general(att, xd, (((1,), (0,)), ((), ())),
-                                preferred_element_type=f32)
+    y = y + dot_f32(att, xd, ((1,), (0,)))
     # state update: h' = exp(tot) h + sum_j exp(tot - cum_j) B_j xd_j^T
-    bdec = bm * jnp.exp(tot - cum)[:, None]
-    state_scr[...] = jnp.exp(tot) * state + jax.lax.dot_general(
-        bdec, xd, (((0,), (0,)), ((), ())),
-        preferred_element_type=f32)
+    bdec = bm * jnp.exp(tot - cum)
+    state_scr[...] = jnp.exp(tot) * state + dot_f32(bdec, xd, ((0,), (0,)))
     # skip connection
     y = y + x * dcoef
     o_ref[0, 0] = y.astype(o_ref.dtype)
@@ -79,8 +79,9 @@ def ssd_bhsp(x, dt, A, Bm, Cm, D, *, chunk: int = 128,
     grid = (b, h, nc)
     xspec = pl.BlockSpec((1, 1, chunk, p_),
                          lambda b_, h_, ci: (b_, h_, ci, 0))
-    dtspec = pl.BlockSpec((1, 1, chunk), lambda b_, h_, ci: (b_, h_, ci))
-    hspec = pl.BlockSpec((1,), lambda b_, h_, ci: (h_,))
+    dtspec = pl.BlockSpec((1, 1, chunk, 1),
+                          lambda b_, h_, ci: (b_, h_, ci, 0))
+    hspec = pl.BlockSpec(memory_space=pltpu.SMEM)
     bcspec = pl.BlockSpec((1, 1, chunk, n),
                           lambda b_, h_, ci: (b_, h_ // reps, ci, 0))
     return pl.pallas_call(
@@ -91,4 +92,4 @@ def ssd_bhsp(x, dt, A, Bm, Cm, D, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((b, h, s, p_), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p_), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bm, Cm, D)
+    )(x, dt[..., None], A, Bm, Cm, D)

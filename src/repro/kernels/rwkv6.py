@@ -9,7 +9,9 @@ is carried across the innermost sequential grid dimension in VMEM scratch.
 Pairwise decays use exponent half-shifting for fp32 safety (same scheme as
 the jnp path in models/rwkv.py — the two implementations cross-check).
 
-Layout: r,k,v,logw (B, H, S, K) blocked (1,1,C,K); u (H, K); grid (B,H,NC).
+Layout: r,k,v,logw (B, H, S, K) blocked (1,1,C,K); u (H, K) viewed as
+(H, 1, K) and blocked (1,1,K), so a block's last two dims are the array's
+(the TPU's 8x128 tiling rule); grid (B,H,NC).
 """
 from __future__ import annotations
 
@@ -19,6 +21,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+def dot_f32(a, b, contract):
+    """fp32 ``dot_general`` at full precision. Mosaic's default runs an
+    fp32 matmul as one bf16 pass: on a v5e that left wkv6 at S=4096 off
+    its fp32 oracle by 4e-3 of the output's scale."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def chunk_cumsum(x, chunk: int):
+    """Inclusive cumsum over the rows of a (chunk, n) block, as one MXU
+    matmul with a lower-triangular ones matrix: Mosaic has no cumsum."""
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    return dot_f32((ii >= jj).astype(jnp.float32), x, ((1,), (0,)))
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_scr, *,
@@ -34,35 +53,31 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_scr, *,
     kc = k_ref[0, 0].astype(f32)
     vc = v_ref[0, 0].astype(f32)
     lw = lw_ref[0, 0].astype(f32)         # log decay, <= 0
-    u = u_ref[0].astype(f32)              # (K,)
+    u = u_ref[0].astype(f32)              # (1, K)
 
-    cum = jnp.cumsum(lw, axis=0)
+    cum = chunk_cumsum(lw, chunk)
     ce = cum - lw                         # exclusive cumsum
     tot = cum[-1:]                        # (1, K)
 
     state = state_scr[...]                # (K, V)
     # inter-chunk
     rd = rc * jnp.exp(ce)
-    y = jax.lax.dot_general(rd, state, (((1,), (0,)), ((), ())),
-                            preferred_element_type=f32)
+    y = dot_f32(rd, state, ((1,), (0,)))
     # intra-chunk (strictly-lower pairs), half-shifted exponents
     rds = rc * jnp.exp(ce - 0.5 * tot)
     ki = kc * jnp.exp(0.5 * tot - cum)
-    att = jax.lax.dot_general(rds, ki, (((1,), (1,)), ((), ())),
-                              preferred_element_type=f32)
+    att = dot_f32(rds, ki, ((1,), (1,)))
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     att = jnp.where(ii > jj, att, 0.0)
-    y = y + jax.lax.dot_general(att, vc, (((1,), (0,)), ((), ())),
-                                preferred_element_type=f32)
+    y = y + dot_f32(att, vc, ((1,), (0,)))
     # diagonal bonus term
-    diag = jnp.sum(rc * kc * u[None, :], axis=1, keepdims=True)
+    diag = jnp.sum(rc * kc * u, axis=1, keepdims=True)
     y = y + diag * vc
     # state update
     kdec = kc * jnp.exp(tot - cum)
-    state_scr[...] = jnp.exp(tot).T * state + jax.lax.dot_general(
-        kdec, vc, (((0,), (0,)), ((), ())),
-        preferred_element_type=f32)
+    state_scr[...] = jnp.exp(tot).T * state + dot_f32(kdec, vc,
+                                                      ((0,), (0,)))
     o_ref[0, 0] = y.astype(o_ref.dtype)
 
 
@@ -76,7 +91,7 @@ def wkv6_bhsk(r, k, v, logw, u, *, chunk: int = 128,
     grid = (b, h, nc)
     spec = pl.BlockSpec((1, 1, chunk, dk),
                         lambda b_, h_, ci: (b_, h_, ci, 0))
-    u_spec = pl.BlockSpec((1, dk), lambda b_, h_, ci: (h_, 0))
+    u_spec = pl.BlockSpec((1, 1, dk), lambda b_, h_, ci: (h_, 0, 0))
     return pl.pallas_call(
         functools.partial(_wkv6_kernel, chunk=chunk),
         grid=grid,
@@ -85,4 +100,4 @@ def wkv6_bhsk(r, k, v, logw, u, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((b, h, s, dk), r.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dk), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u)
+    )(r, k, v, logw, u.reshape(h, 1, dk))

@@ -3,22 +3,18 @@
 ``make_production_mesh`` is a FUNCTION (importing this module never touches
 jax device state): 16x16 = 256 chips per pod ("data", "model"); multi-pod
 adds a leading "pod" axis (2 x 16 x 16 = 512 chips). The dry-run forces 512
-host devices via XLA_FLAGS (see launch/dryrun.py lines 1–2).
+host devices via XLA_FLAGS (see launch/dryrun.py lines 1–2). Every mesh has
+Auto axes (``sharding/mesh.py``).
 """
 from __future__ import annotations
 
-import jax
+from repro.sharding.mesh import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (reduced test meshes, provisioner search points)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_for_chips(chips: int, model_axis: int = 16, *,

@@ -12,6 +12,7 @@ provenance — is exercised end to end.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 from pathlib import Path
 
@@ -22,10 +23,12 @@ from jax.sharding import NamedSharding
 from repro.configs.base import get_arch, list_archs
 from repro.core.acai import AcaiProject
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
+from repro.sharding.mesh import make_mesh
 from repro.sharding import rules as SR
 from repro.train.checkpoints import CheckpointManager
-from repro.train.fault import TrainSupervisor
+from repro.train.fault import SupervisorReport, TrainSupervisor
 from repro.train.optimizer import OptimizerConfig, opt_state_specs
 from repro.train.train_step import (TrainConfig, make_opt_state,
                                     make_train_step)
@@ -45,9 +48,66 @@ def build_sharded_train(cfg, tcfg, ocfg, mesh):
     named = lambda t: jax.tree.map(
         lambda sp: NamedSharding(mesh, sp), t,
         is_leaf=lambda x: type(x).__name__ == "PartitionSpec")
-    return jax.jit(step, in_shardings=(named(pspecs), named(ospecs), None),
-                   out_shardings=(named(pspecs), named(ospecs), None),
-                   donate_argnums=(0, 1)), pspecs
+    pshard, oshard = named(pspecs), named(ospecs)
+    return jax.jit(step, in_shardings=(pshard, oshard, None),
+                   out_shardings=(pshard, oshard, None),
+                   donate_argnums=(0, 1)), pshard, oshard
+
+
+@dataclasses.dataclass
+class TrainResult:
+    state: dict                    # {"params", "opt", "step"}
+    report: SupervisorReport
+    losses: list[float]            # per step run, in order
+    ckpt: CheckpointManager
+
+
+def init_train(cfg, tcfg: TrainConfig, ocfg: OptimizerConfig, *,
+               mesh=None, seed: int = 0):
+    """The jitted train step and its freshly initialised (params, opt).
+    With ``mesh`` the step is the sharded production assembly, and the
+    state is born sharded rather than gathered whole on one device."""
+    pshard = oshard = None
+    if mesh is not None:
+        step, pshard, oshard = build_sharded_train(cfg, tcfg, ocfg, mesh)
+    else:
+        step = jax.jit(make_train_step(cfg, tcfg, ocfg),
+                       donate_argnums=(0, 1))
+    params = jax.jit(functools.partial(M.init_params, cfg),
+                     out_shardings=pshard)(jax.random.PRNGKey(seed))
+    opt = jax.jit(functools.partial(make_opt_state, tcfg=tcfg),
+                  out_shardings=oshard)(params)
+    return step, params, opt
+
+
+def train(cfg, project: AcaiProject, run: str, *, steps: int, seq_len: int,
+          global_batch: int, data_vocab: int, save_every: int,
+          tcfg: TrainConfig = TrainConfig(), lr: float = 3e-3,
+          mesh=None, seed: int = 0) -> TrainResult:
+    """Supervised training of ``cfg`` on the seeded synthetic stream
+    (``data_vocab`` tokens), checkpointing into ``project``'s data lake."""
+    ocfg = OptimizerConfig(lr=lr, warmup_steps=5, total_steps=steps,
+                           weight_decay=0.0)
+    step, params, opt = init_train(cfg, tcfg, ocfg, mesh=mesh, seed=seed)
+    pipe = TokenPipeline(DataConfig(
+        seed=seed, vocab_size=data_vocab, seq_len=seq_len,
+        global_batch=global_batch, markov_temp=2.5), cfg)
+    pipe.register(project, f"{run}-data", creator="trainer")
+    ckpt = CheckpointManager(project, run)
+    sup = TrainSupervisor(ckpt, save_every=save_every)
+    losses = []
+
+    def logged_step(params, opt, batch):
+        params, opt, metrics = step(params, opt, batch)
+        losses.append(metrics["loss"])
+        return params, opt, metrics
+
+    def batch_fn(i):
+        return jax.tree.map(jnp.asarray, pipe.batch_at(i))
+
+    state, report = sup.run(logged_step, {"params": params, "opt": opt,
+                                          "step": 0}, steps, batch_fn)
+    return TrainResult(state, report, [float(x) for x in losses], ckpt)
 
 
 def main():
@@ -65,38 +125,22 @@ def main():
     ap.add_argument("--workdir", default="/tmp/acai-train")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_arch(args.arch)
     if not args.full:
         cfg = cfg.reduced()
-    tcfg = TrainConfig(remat=args.remat)
-    ocfg = OptimizerConfig(lr=args.lr, warmup_steps=5,
-                           total_steps=args.steps, weight_decay=0.0)
-
+    mesh = None
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh(shape, ("data", "model")[:len(shape)])
-        step, _ = build_sharded_train(cfg, tcfg, ocfg, mesh)
-    else:
-        step = jax.jit(make_train_step(cfg, tcfg, ocfg))
-
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    opt = make_opt_state(params, tcfg)
-    pipe = TokenPipeline(DataConfig(
-        vocab_size=min(cfg.vocab_size, 64), seq_len=args.seq_len,
-        global_batch=args.global_batch, markov_temp=2.5), cfg)
-
-    project = AcaiProject("train", Path(args.workdir))
-    pipe.register(project, f"{args.arch}-data", creator="trainer")
-    ckpt = CheckpointManager(project, f"{args.arch}-run")
-    sup = TrainSupervisor(ckpt, save_every=args.save_every)
-
-    def batch_fn(i):
-        return jax.tree.map(jnp.asarray, pipe.batch_at(i))
-
-    state, report = sup.run(step, {"params": params, "opt": opt,
-                                   "step": 0}, args.steps, batch_fn)
-    print(f"done: {report.steps_run} steps, {report.checkpoints} ckpts, "
-          f"latest={ckpt.latest_step()}")
+        mesh = make_mesh(shape, ("data", "model")[:len(shape)])
+    res = train(cfg, AcaiProject("train", Path(args.workdir)),
+                f"{args.arch}-run", steps=args.steps, seq_len=args.seq_len,
+                global_batch=args.global_batch,
+                data_vocab=min(cfg.vocab_size, 64),
+                save_every=args.save_every,
+                tcfg=TrainConfig(remat=args.remat), lr=args.lr, mesh=mesh)
+    print(f"done: {res.report.steps_run} steps, {res.report.checkpoints} "
+          f"ckpts, latest={res.ckpt.latest_step()}")
 
 
 if __name__ == "__main__":

@@ -408,8 +408,6 @@ def moe_block(p, x, cfg: ArchConfig, *, capacity: Optional[int] = None):
     scatter indexed on the sharded expert dim without replicating the
     buffers; the explicit shard_map path avoids both. See DESIGN.md §5.)
     """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
     from repro.sharding.rules import current_rules
 
     m = cfg.moe
@@ -443,7 +441,7 @@ def moe_block(p, x, cfg: ArchConfig, *, capacity: Optional[int] = None):
                               ("model", mesh.axis_names),
                               shared_w=(sg, su, sd_))
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(b_ax, None, None), P(None, None),
                       P("model", None, None), P("model", None, None),
@@ -451,7 +449,7 @@ def moe_block(p, x, cfg: ArchConfig, *, capacity: Optional[int] = None):
                       P(None, "model"), P(None, "model"),
                       P("model", None)),
             out_specs=(P(b_ax, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"],
           p["shared"]["w_gate"], p["shared"]["w_up"],
           p["shared"]["w_down"])
@@ -462,13 +460,13 @@ def moe_block(p, x, cfg: ArchConfig, *, capacity: Optional[int] = None):
         return _moe_local(xl, router, wg, wu, wd, cfg, e0, n_local,
                           ("model", mesh.axis_names))
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(b_ax, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(b_ax, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if m.n_shared_experts:

@@ -13,8 +13,8 @@ all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute
 (async -start forms included, -done skipped), with a size correction for
 reduce-scatter (wire bytes ~ group_size x result bytes).
 
-Hardware constants (TPU v5e-class target, per assignment):
-    197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
+Hardware constants come from ``DEVICE_PEAKS``, keyed by the device kind
+JAX reports; the dry-run's target chip is the TPU v5e.
 """
 from __future__ import annotations
 
@@ -22,9 +22,55 @@ import dataclasses
 import re
 from typing import Any, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class HardwareSpec:
+    """One accelerator family's roofline constants.
+
+    ``scale_dim`` names the resource dimension whose amount multiplies
+    aggregate compute/bandwidth (e.g. ``"chips"`` on a TPU pod slice);
+    ``ref_chips`` is the amount the registered cost models are normalized
+    to (cost models give *total* work, so ``n = config[scale_dim] /
+    ref_chips`` divides it across the slice). ``startup_s`` is the
+    per-job provisioning + compile tax the roofline terms sit on top of.
+    ``ici_bw`` is per link; 0 leaves out the interconnect term.
+    """
+    family: str
+    peak_flops: float
+    hbm_bw: float
+    ici_bw: float = 0.0
+    startup_s: float = 0.0
+    scale_dim: Optional[str] = None
+    ref_chips: float = 1.0
+
+    def chips(self, config: dict) -> float:
+        if self.scale_dim is None:
+            return 1.0
+        return max(float(config.get(self.scale_dim, self.ref_chips))
+                   / self.ref_chips, 1e-9)
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 links of 50 GB/s).
+DEVICE_PEAKS: dict[str, HardwareSpec] = {
+    "TPU v5 lite": HardwareSpec("TPU v5 lite", peak_flops=197e12,
+                                hbm_bw=819e9, ici_bw=50e9,
+                                scale_dim="chips", ref_chips=1.0),
+}
+
+
+def device_peaks(device_kind: str) -> HardwareSpec:
+    """The peaks of one chip of ``device_kind``; an unknown kind raises."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to DEVICE_PEAKS with "
+                       f"their source") from None
+
+
+TARGET = DEVICE_PEAKS["TPU v5 lite"]      # the dry-run's chip
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -112,15 +158,15 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / TARGET.peak_flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / TARGET.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / TARGET.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -143,7 +189,7 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """Fraction of chip peak spent on *useful* model FLOPs if the step
         ran at the roofline estimate: MODEL_FLOPS / (chips*peak*step_time)."""
-        denom = self.n_chips * PEAK_FLOPS * self.step_time_s
+        denom = self.n_chips * TARGET.peak_flops * self.step_time_s
         return self.model_flops / denom if denom else float("nan")
 
     def as_dict(self) -> dict[str, Any]:

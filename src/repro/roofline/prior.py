@@ -25,40 +25,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Optional, Union
 
-from repro.roofline.analysis import HBM_BW, ICI_BW, PEAK_FLOPS
+from repro.roofline.analysis import HardwareSpec  # noqa: F401 (re-export)
 
 CostFn = Union[float, Callable[[dict], float]]
-
-
-@dataclasses.dataclass(frozen=True)
-class HardwareSpec:
-    """One accelerator family's roofline constants.
-
-    ``scale_dim`` names the resource dimension whose amount multiplies
-    aggregate compute/bandwidth (e.g. ``"chips"`` on a TPU pod slice);
-    ``ref_chips`` is the amount the registered cost models are normalized
-    to (cost models give *total* work, so ``n = config[scale_dim] /
-    ref_chips`` divides it across the slice). ``startup_s`` is the
-    per-job provisioning + compile tax the roofline terms sit on top of.
-    """
-    family: str
-    peak_flops: float
-    hbm_bw: float
-    ici_bw: float = ICI_BW
-    startup_s: float = 0.0
-    scale_dim: Optional[str] = None
-    ref_chips: float = 1.0
-
-    def chips(self, config: dict) -> float:
-        if self.scale_dim is None:
-            return 1.0
-        return max(float(config.get(self.scale_dim, self.ref_chips))
-                   / self.ref_chips, 1e-9)
-
-
-# The repo's target family (TPU v5e-class, constants from analysis.py).
-TPU_V5E = HardwareSpec("tpu", PEAK_FLOPS, HBM_BW, ICI_BW,
-                       scale_dim="chips", ref_chips=1.0)
 
 
 def roofline_ceiling_s(flops: float, nbytes: float,

@@ -18,8 +18,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
+
+from repro.sharding.mesh import auto_axes
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
@@ -72,11 +73,11 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
         outs = jnp.where(sid == s - 1, outs, 0)
         return jax.lax.psum(outs, axis)
 
-    out = shard_map(
-        body, mesh=mesh,
+    out = jax.shard_map(
+        body, mesh=auto_axes(mesh),
         in_specs=(P(axis), P()),      # params stage-sharded, micro replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, micro)
     return out.reshape((b,) + out.shape[2:])
 

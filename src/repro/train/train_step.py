@@ -20,7 +20,8 @@ from repro.train.optimizer import (OptimizerConfig, adamw_update,
 class TrainConfig:
     microbatches: int = 1
     remat: str = "full"              # none | full | dots
-    attn_impl: str = "xla"           # xla | pallas | pallas-interpret
+    attn_impl: str = "xla"           # xla | xla-bf16-logits (the Pallas
+    # flash kernel has no VJP, so make_train_step refuses it)
     grad_compression: Optional[str] = None    # None | bf16 | int8
     compute_dtype: str = "bfloat16"
     # cast params once per step BEFORE the layer scan: FSDP gathers then
@@ -50,6 +51,11 @@ def make_loss_fn(cfg: ArchConfig, tcfg: TrainConfig):
 
 def make_train_step(cfg: ArchConfig, tcfg: TrainConfig,
                     ocfg: OptimizerConfig):
+    if tcfg.attn_impl.startswith("pallas"):
+        raise ValueError(
+            f"attn_impl={tcfg.attn_impl!r} cannot train: the Pallas flash "
+            f"attention kernel defines no VJP, so jax.grad cannot "
+            f"differentiate it; train with attn_impl='xla'")
     loss_fn = make_loss_fn(cfg, tcfg)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 

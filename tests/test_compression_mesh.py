@@ -12,7 +12,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.train.compression import compressed_psum
 
@@ -22,8 +21,8 @@ x = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.0
 def f(kind):
     def body(xl):
         return compressed_psum(xl[0], "d", kind)[None]
-    return shard_map(body, mesh=mesh, in_specs=(P("d", None),),
-                     out_specs=P("d", None), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P("d", None),),
+                         out_specs=P("d", None), check_vma=False)
 
 want = np.asarray(x.sum(0))
 out = {}

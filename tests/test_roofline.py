@@ -104,3 +104,20 @@ def test_collective_parse_sharded_program():
     c = _compile(lambda x: x + 1, jax.ShapeDtypeStruct((8,), jnp.float32))
     cost = module_cost(c.as_text())
     assert cost.coll_bytes == 0
+
+
+def test_device_peaks_keyed_by_device_kind():
+    from repro.roofline.analysis import device_peaks
+    v5e = device_peaks("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        device_peaks("TPU v0 unknown")
+
+
+def test_tuning_family_is_interpret_on_cpu_and_unknown_raises():
+    from repro.core.provision import autotune as AT
+    assert AT.default_family() == "interpret"
+    assert AT._family_hw("interpret") is AT.INTERPRET_HW
+    assert AT._family_hw("TPU v5 lite").peak_flops == 197e12
+    with pytest.raises(KeyError):
+        AT._family_hw("tpu")

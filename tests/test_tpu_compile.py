@@ -1,0 +1,146 @@
+"""Compiles for a described TPU v5e (a 2x2 host) with no chip attached.
+
+Nothing runs: the TPU compiler refuses here what the chip would refuse
+(block tiling, VMEM, a program that does not fit HBM). Covered: the four
+Pallas kernels at real widths, the olmo-1b serve step at full width and
+depth, and the olmo-1b train step sharded over the 2x2 mesh.
+
+The topology is described inside a module fixture, never at import, so
+only the worker that runs this file loads the TPU compiler.
+"""
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
+
+from repro.configs.base import get_arch
+from repro.kernels import ops
+
+HBM_BYTES = 16e9            # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")    # no compiler logs in /tmp
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent
+        # cache but cannot be read back without one: keep the cache off
+        prev = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        yield desc
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_case(name, sds):
+    """(fn, args) for one kernel at the widths of the config it serves."""
+    bf16, f32, seq = jnp.bfloat16, jnp.float32, 4096
+    if name in ("flash_attention", "decode_attention"):
+        c = get_arch("olmo-1b")
+        h, kv, d = c.n_heads, c.n_kv_heads, c.resolved_head_dim
+        if name == "flash_attention":
+            return ops.flash_attention, (sds((1, seq, h, d), bf16),
+                                         sds((1, seq, kv, d), bf16),
+                                         sds((1, seq, kv, d), bf16))
+        cache = (8, 2048, kv, d)
+        return ops.decode_attention, (sds((8, 1, h, d), bf16),
+                                      sds(cache, bf16), sds(cache, bf16),
+                                      sds((8,), jnp.int32))
+    if name == "wkv6":
+        c = get_arch("rwkv6-7b")
+        k = c.rwkv.head_dim
+        h = c.d_model // k
+        return ops.wkv6, (sds((1, seq, h, k), f32),) * 4 + (sds((h, k), f32),)
+    c = get_arch("zamba2-7b")
+    mc = c.mamba
+    h = mc.n_heads(c.d_model)
+    bc = sds((1, seq, mc.n_groups, mc.d_state), f32)
+    return ops.mamba2_ssd, (sds((1, seq, h, mc.head_dim), f32),
+                            sds((1, seq, h), f32), sds((h,), f32), bc, bc,
+                            sds((h,), f32))
+
+
+@pytest.mark.parametrize("name", ["flash_attention", "decode_attention",
+                                  "wkv6", "mamba2_ssd"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = _kernel_case(name, sds)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()     # the Mosaic kernel
+
+
+def test_olmo_serve_step_compiles_and_fits_one_chip(one_chip):
+    from repro.models import model as M
+    from repro.models import transformer as T
+    from repro.serve.decode import make_serve_step
+
+    cfg = get_arch("olmo-1b")
+    slots, buf = 8, 1024
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(M.init_params, cfg),
+                                    jax.random.PRNGKey(0)))
+    states = on_chip(jax.eval_shape(functools.partial(
+        T.init_decode_state, cfg, slots, buf)))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+                     "cache_len": jax.ShapeDtypeStruct((slots,),
+                                                       jnp.int32)})
+    compiled = jax.jit(make_serve_step(cfg, buf)).lower(
+        params, states, batch).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert used < HBM_BYTES, used
+
+
+def test_sharded_olmo_train_step_compiles_on_2x2(topo):
+    from repro.launch.train import build_sharded_train
+    from repro.models import model as M
+    from repro.sharding import rules as SR
+    from repro.train.optimizer import OptimizerConfig
+    from repro.train.train_step import TrainConfig, make_opt_state
+
+    cfg = dataclasses.replace(get_arch("olmo-1b"), n_layers=4)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    tcfg = TrainConfig()
+    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=5, total_steps=5)
+    try:
+        step, _, _ = build_sharded_train(cfg, tcfg, ocfg, mesh)
+        params = jax.eval_shape(functools.partial(M.init_params, cfg),
+                                jax.random.PRNGKey(0))
+        opt = jax.eval_shape(functools.partial(make_opt_state, tcfg=tcfg),
+                             params)
+        tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32)
+        compiled = step.lower(params, opt, {"tokens": tokens,
+                                            "labels": tokens}).compile()
+    finally:
+        SR.set_rules(None)
+    mem = compiled.memory_analysis()
+    state_bytes = 16 * cfg.n_params()        # fp32 params + AdamW moments
+    # FSDP over "data" and TP over "model": each chip holds well under
+    # half of the state (the tied embedding shards over "model" only)
+    assert mem.argument_size_in_bytes < state_bytes / 2
+    assert "all-gather" in compiled.as_text()

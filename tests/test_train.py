@@ -73,6 +73,12 @@ def test_train_loss_decreases():
     assert losses[-1] < losses[0] - 1.0, losses
 
 
+@pytest.mark.parametrize("attn_impl", ["pallas", "pallas-interpret"])
+def test_train_step_refuses_pallas_attention(attn_impl):
+    with pytest.raises(ValueError, match="no VJP"):
+        _tiny_setup(attn_impl=attn_impl)
+
+
 def test_microbatch_equals_fullbatch_grads():
     cfg, params, _, _, pipe = _tiny_setup()
     batch = jax.tree.map(jnp.asarray, pipe.batch_at(0))
