@@ -24,6 +24,8 @@ import time
 from pathlib import Path
 from typing import Iterable, Optional
 
+from repro.core.trace import span
+
 
 class DataLakeError(RuntimeError):
     pass
@@ -81,12 +83,14 @@ class Storage:
 
     # -- blobs ("S3") --------------------------------------------------
     def _put_blob(self, data: bytes) -> str:
-        h = hashlib.sha256(data).hexdigest()
+        with span("lake/hash"):
+            h = hashlib.sha256(data).hexdigest()
         p = self.blob_dir / h
         if not p.exists():
-            tmp = p.with_suffix(".tmp-%d" % os.getpid())
-            tmp.write_bytes(data)
-            os.replace(tmp, p)
+            with span("lake/write"):
+                tmp = p.with_suffix(".tmp-%d" % os.getpid())
+                tmp.write_bytes(data)
+                os.replace(tmp, p)
         return h
 
     def _get_blob(self, blob: str) -> bytes:

@@ -40,6 +40,7 @@ from repro.core.engine.lifecycle import (IllegalTransition, JobPreempted,
                                          TransientJobError)
 from repro.core.engine.logparse import parse_log
 from repro.core.engine.registry import Job, JobRegistry
+from repro.core.trace import span
 
 
 # per-segment billing accumulates into job.cost from worker threads — a
@@ -143,6 +144,10 @@ class LocalRunner(Runner):
         return redirect_stdout(log_buf)
 
     def launch(self, job: Job) -> None:
+        with span("engine/launch"):
+            self._launch(job)
+
+    def _launch(self, job: Job) -> None:
         bus, reg = self.bus, self.registry
         epoch = job.epoch        # incarnation this launch belongs to
         try:
@@ -165,11 +170,12 @@ class LocalRunner(Runner):
             if job.spec.input_fileset and self.datalake is not None:
                 bus.publish(TOPIC_JOB_PROGRESS,
                             {"job_id": job.job_id, "stage": "downloading"})
-                self.datalake.filesets.materialize(job.spec.input_fileset,
-                                                   workdir)
+                with span("engine/materialize"):
+                    self.datalake.filesets.materialize(
+                        job.spec.input_fileset, workdir)
             bus.publish(TOPIC_JOB_PROGRESS,
                         {"job_id": job.job_id, "stage": "running"})
-            with self._capture(log_buf):
+            with self._capture(log_buf), span("engine/job_fn"):
                 result = job.spec.fn(workdir, job) if job.spec.fn else None
             if job.epoch != epoch:
                 # superseded while the fn ran (preempted, but it never
@@ -192,7 +198,8 @@ class LocalRunner(Runner):
             runtime = time.perf_counter() - t0
             job.runtime = job.spec.duration if job.spec.duration is not None \
                 else runtime
-            ref = self._upload_outputs(job, workdir, bus)
+            with span("engine/upload"):
+                ref = self._upload_outputs(job, workdir, bus)
             if ref is not None:
                 delta["fileset"] = ref
             self._finalize(job, log_buf.getvalue(), JobState.FINISHED,
@@ -274,64 +281,65 @@ class LocalRunner(Runner):
                   epoch: Optional[int] = None,
                   outputs: Optional[dict] = None,
                   transient: bool = False) -> None:
-        if epoch is not None and job.epoch != epoch:
-            # a superseded incarnation must not write the registry, bill,
-            # or publish: the job is live again (re-queued or relaunched)
-            # and a FINISHED/FAILED here would terminal-ize it under the
-            # new incarnation's feet
-            return
-        # the job may have been killed while the fn ran (thread workers):
-        # keep the registry's terminal state, don't overwrite it
-        if self.registry.get(job.job_id).state in TERMINAL_STATES:
-            state = self.registry.get(job.job_id).state
-        else:
-            try:
-                # epoch-guarded write: the check above is advisory (the
-                # preemption can land between it and here), but the
-                # registry re-checks the epoch under its own lock — a
-                # zombie can never terminal-ize the live incarnation
-                if self.registry.set_state(job.job_id, state, error=error,
-                                           expect_epoch=epoch) is None:
-                    return              # superseded mid-flight: hands off
-            except IllegalTransition:   # killed between check and set
+        with span("engine/finalize"):
+            if epoch is not None and job.epoch != epoch:
+                # a superseded incarnation must not write the registry, bill,
+                # or publish: the job is live again (re-queued or relaunched)
+                # and a FINISHED/FAILED here would terminal-ize it under the
+                # new incarnation's feet
+                return
+            # the job may have been killed while the fn ran (thread workers):
+            # keep the registry's terminal state, don't overwrite it
+            if self.registry.get(job.job_id).state in TERMINAL_STATES:
                 state = self.registry.get(job.job_id).state
-        if epoch is not None and job.epoch != epoch:
-            return      # superseded on the IllegalTransition path: the
-                        # job re-queued under us — no billing/publish
-        if outputs:
-            # commit the staged result/fileset delta only now, with the
-            # terminal state claimed: a zombie never reaches this line
-            job.outputs.update(outputs)
-        if job.runtime is not None:
-            # accumulate, not overwrite: preempted incarnations already
-            # billed their partial segments
-            _bill_segment(resolve_pricing(self.pricing, job), job,
-                          job.runtime)
-        if self.datalake is not None:
-            meta = parse_log(log_text)      # intelligent log parser
-            if meta:
-                self.datalake.metadata.put(job.job_id, **meta)
-            self.datalake.metadata.put(job.job_id, runtime=job.runtime,
-                                       cost=job.cost, state=state.value)
-            # log text goes to the lake, not the metadata store: metadata
-            # values are bisect-indexed and rewritten wholesale on every
-            # put, so logs there would grow completion cost quadratically
-            self.datalake.storage.upload(f"/.logs/{job.job_id}.log",
-                                         log_text.encode(),
-                                         creator=job.spec.user)
-        job.outputs["log"] = log_text
-        msg = {"job_id": job.job_id, "status": state.value}
-        if transient and state == JobState.FAILED:
-            # transient-vs-fatal rides the terminal event: the scheduler's
-            # retry policy reads it without re-parsing the traceback
-            msg["transient"] = True
-        if epoch is not None:
-            # stamp the incarnation: the scheduler drops terminal events
-            # whose epoch predates the job's current one (a worker that
-            # finished after its job was preempted and relaunched must
-            # not settle the new incarnation's reservation)
-            msg["epoch"] = epoch
-        self.bus.publish(TOPIC_CONTAINER_STATUS, msg)
+            else:
+                try:
+                    # epoch-guarded write: the check above is advisory (the
+                    # preemption can land between it and here), but the
+                    # registry re-checks the epoch under its own lock — a
+                    # zombie can never terminal-ize the live incarnation
+                    if self.registry.set_state(job.job_id, state, error=error,
+                                               expect_epoch=epoch) is None:
+                        return              # superseded mid-flight: hands off
+                except IllegalTransition:   # killed between check and set
+                    state = self.registry.get(job.job_id).state
+            if epoch is not None and job.epoch != epoch:
+                return      # superseded on the IllegalTransition path: the
+                            # job re-queued under us — no billing/publish
+            if outputs:
+                # commit the staged result/fileset delta only now, with the
+                # terminal state claimed: a zombie never reaches this line
+                job.outputs.update(outputs)
+            if job.runtime is not None:
+                # accumulate, not overwrite: preempted incarnations already
+                # billed their partial segments
+                _bill_segment(resolve_pricing(self.pricing, job), job,
+                              job.runtime)
+            if self.datalake is not None:
+                meta = parse_log(log_text)      # intelligent log parser
+                if meta:
+                    self.datalake.metadata.put(job.job_id, **meta)
+                self.datalake.metadata.put(job.job_id, runtime=job.runtime,
+                                           cost=job.cost, state=state.value)
+                # log text goes to the lake, not the metadata store: metadata
+                # values are bisect-indexed and rewritten wholesale on every
+                # put, so logs there would grow completion cost quadratically
+                self.datalake.storage.upload(f"/.logs/{job.job_id}.log",
+                                             log_text.encode(),
+                                             creator=job.spec.user)
+            job.outputs["log"] = log_text
+            msg = {"job_id": job.job_id, "status": state.value}
+            if transient and state == JobState.FAILED:
+                # transient-vs-fatal rides the terminal event: the scheduler's
+                # retry policy reads it without re-parsing the traceback
+                msg["transient"] = True
+            if epoch is not None:
+                # stamp the incarnation: the scheduler drops terminal events
+                # whose epoch predates the job's current one (a worker that
+                # finished after its job was preempted and relaunched must
+                # not settle the new incarnation's reservation)
+                msg["epoch"] = epoch
+            self.bus.publish(TOPIC_CONTAINER_STATUS, msg)
 
 
 class _ThreadLocalStdout(io.TextIOBase):

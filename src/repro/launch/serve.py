@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, get_arch, list_archs
+from repro.core.trace import span
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.models import transformer as T
@@ -42,8 +43,9 @@ def serve(cfg: ArchConfig, params, prompts: list[list[int]], *,
     if longest > buffer_len:
         raise ValueError(f"a request needs {longest} cache positions; "
                          f"buffer_len is {buffer_len}")
-    states = T.init_decode_state(cfg, slots, buffer_len)
-    step = jax.jit(make_serve_step(cfg, buffer_len))
+    with span("serve/init"):
+        states = T.init_decode_state(cfg, slots, buffer_len)
+        step = jax.jit(make_serve_step(cfg, buffer_len))
 
     slot_req = [-1] * slots
     slot_prompt: list[list[int]] = [[] for _ in range(slots)]
@@ -72,26 +74,29 @@ def serve(cfg: ArchConfig, params, prompts: list[list[int]], *,
     max_ticks = len(prompts) * longest
     while done < len(prompts) and ticks < max_ticks:
         ticks += 1
-        batch = {"tokens": jnp.asarray(cur), "cache_len": cache_len}
-        logits, states, nxt = step(params, states, batch)
-        cache_len = cache_len + 1
-        nxt = np.asarray(nxt)
-        for s in range(slots):
-            r = slot_req[s]
-            if r < 0:
-                continue
-            if slot_prompt[s]:                      # still prefilling
-                cur[s, 0] = slot_prompt[s].pop(0)
-                continue
-            if not produced[r]:
-                prompt_logits[r] = np.asarray(logits[s, -1], np.float32)
-            produced[r].append(int(nxt[s]))
-            cur[s, 0] = int(nxt[s])
-            if len(produced[r]) >= max_new:
-                done += 1
-                # reset this slot's cache and grab the next request
-                cache_len = cache_len.at[s].set(0)
-                refill(s)
+        with span("serve/dispatch"):
+            batch = {"tokens": jnp.asarray(cur), "cache_len": cache_len}
+            logits, states, nxt = step(params, states, batch)
+            cache_len = cache_len + 1
+        with span("serve/sync"):
+            nxt = np.asarray(nxt)
+        with span("serve/host"):
+            for s in range(slots):
+                r = slot_req[s]
+                if r < 0:
+                    continue
+                if slot_prompt[s]:                      # still prefilling
+                    cur[s, 0] = slot_prompt[s].pop(0)
+                    continue
+                if not produced[r]:
+                    prompt_logits[r] = np.asarray(logits[s, -1], np.float32)
+                produced[r].append(int(nxt[s]))
+                cur[s, 0] = int(nxt[s])
+                if len(produced[r]) >= max_new:
+                    done += 1
+                    # reset this slot's cache and grab the next request
+                    cache_len = cache_len.at[s].set(0)
+                    refill(s)
     if done < len(prompts):
         raise RuntimeError(f"served {done}/{len(prompts)} requests in "
                            f"{ticks} steps")

@@ -14,6 +14,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import functools
+import statistics
 from pathlib import Path
 
 import jax
@@ -22,6 +23,7 @@ from jax.sharding import NamedSharding
 
 from repro.configs.base import get_arch, list_archs
 from repro.core.acai import AcaiProject
+from repro.core.trace import span
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
@@ -88,11 +90,14 @@ def train(cfg, project: AcaiProject, run: str, *, steps: int, seq_len: int,
     (``data_vocab`` tokens), checkpointing into ``project``'s data lake."""
     ocfg = OptimizerConfig(lr=lr, warmup_steps=5, total_steps=steps,
                            weight_decay=0.0)
-    step, params, opt = init_train(cfg, tcfg, ocfg, mesh=mesh, seed=seed)
+    with span("train/init"):
+        step, params, opt = init_train(cfg, tcfg, ocfg, mesh=mesh,
+                                       seed=seed)
     pipe = TokenPipeline(DataConfig(
         seed=seed, vocab_size=data_vocab, seq_len=seq_len,
         global_batch=global_batch, markov_temp=2.5), cfg)
-    pipe.register(project, f"{run}-data", creator="trainer")
+    with span("train/register"):
+        pipe.register(project, f"{run}-data", creator="trainer")
     ckpt = CheckpointManager(project, run)
     sup = TrainSupervisor(ckpt, save_every=save_every)
     losses = []
@@ -105,8 +110,14 @@ def train(cfg, project: AcaiProject, run: str, *, steps: int, seq_len: int,
     def batch_fn(i):
         return jax.tree.map(jnp.asarray, pipe.batch_at(i))
 
-    state, report = sup.run(logged_step, {"params": params, "opt": opt,
-                                          "step": 0}, steps, batch_fn)
+    with span("train/steps"):
+        state, report = sup.run(logged_step, {"params": params, "opt": opt,
+                                              "step": 0}, steps, batch_fn)
+    if report.step_s:
+        # the engine's log parser attaches these to the job's metadata
+        print(f"[[acai:step_s_median={statistics.median(report.step_s)},"
+              f"straggler_steps={len(report.straggler_steps)},"
+              f"ckpt_save_s={sum(report.save_s)}]]")
     return TrainResult(state, report, [float(x) for x in losses], ckpt)
 
 

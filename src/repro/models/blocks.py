@@ -274,14 +274,15 @@ def _cache_insert(cache, new, idx):
     always partitionable); "scatter" writes only B rows via jnp scatter
     (cheaper HBM traffic IF GSPMD partitions it against the sharded seq
     dim — measured per cell in §Perf)."""
-    if CACHE_INSERT_IMPL == "scatter":
-        b = cache.shape[0]
-        return cache.at[jnp.arange(b), idx].set(
-            new[:, 0].astype(cache.dtype), mode="drop")
-    s = cache.shape[1]
-    onehot = (jnp.arange(s)[None, :] == idx[:, None]).astype(cache.dtype)
-    return cache * (1 - onehot)[:, :, None, None] + \
-        onehot[:, :, None, None] * new.astype(cache.dtype)
+    with jax.named_scope("cache_insert"):
+        if CACHE_INSERT_IMPL == "scatter":
+            b = cache.shape[0]
+            return cache.at[jnp.arange(b), idx].set(
+                new[:, 0].astype(cache.dtype), mode="drop")
+        s = cache.shape[1]
+        onehot = (jnp.arange(s)[None, :] == idx[:, None]).astype(cache.dtype)
+        return cache * (1 - onehot)[:, :, None, None] + \
+            onehot[:, :, None, None] * new.astype(cache.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -297,10 +298,11 @@ def init_mlp(cfg: ArchConfig, key, d_ff=None):
 
 
 def mlp_block(p, x):
-    cd = x.dtype
-    g = jax.nn.silu(x @ p["w_gate"].astype(cd))
-    u = x @ p["w_up"].astype(cd)
-    return (g * u) @ p["w_down"].astype(cd)
+    with jax.named_scope("mlp"):
+        cd = x.dtype
+        g = jax.nn.silu(x @ p["w_gate"].astype(cd))
+        u = x @ p["w_up"].astype(cd)
+        return (g * u) @ p["w_down"].astype(cd)
 
 
 # ---------------------------------------------------------------------------

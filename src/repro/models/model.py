@@ -59,16 +59,17 @@ def embed_tokens(params, tokens, cfg: ArchConfig, compute_dtype):
 
 
 def lm_logits(params, x, cfg: ArchConfig):
-    xf = B.apply_norm(params["final_norm"], x, cfg)
-    if cfg.tie_embeddings:
-        w = params["embed"].T
-    else:
-        w = params["lm_head"]
-    logits = xf @ w.astype(xf.dtype)
-    if cfg.n_codebooks:
-        b, s, _ = logits.shape
-        logits = logits.reshape(b, s, cfg.n_codebooks, cfg.vocab_size)
-    return logits
+    with jax.named_scope("lm_head"):
+        xf = B.apply_norm(params["final_norm"], x, cfg)
+        if cfg.tie_embeddings:
+            w = params["embed"].T
+        else:
+            w = params["lm_head"]
+        logits = xf @ w.astype(xf.dtype)
+        if cfg.n_codebooks:
+            b, s, _ = logits.shape
+            logits = logits.reshape(b, s, cfg.n_codebooks, cfg.vocab_size)
+        return logits
 
 
 def forward(params, tokens, cfg: ArchConfig, ctx: dict, states=None):

@@ -86,11 +86,12 @@ def layer_fwd(block: str, p, x, cfg: ArchConfig, ctx: dict,
     if block in ("dense", "moe", "shared_attn"):
         h = B.apply_norm(p["ln1"], x, cfg)
         kv_cache = state if decode else None
-        o, new_cache = B.attention_block(
-            p["attn"], h, cfg, rope=ctx.get("rope"),
-            positions=ctx.get("positions"),
-            kv_cache=kv_cache, cache_len=ctx.get("cache_len"),
-            attn_impl=ctx.get("attn_impl", "xla"))
+        with jax.named_scope("attention"):
+            o, new_cache = B.attention_block(
+                p["attn"], h, cfg, rope=ctx.get("rope"),
+                positions=ctx.get("positions"),
+                kv_cache=kv_cache, cache_len=ctx.get("cache_len"),
+                attn_impl=ctx.get("attn_impl", "xla"))
         x = x + o
         h = B.apply_norm(p["ln2"], x, cfg)
         if block == "moe":

@@ -18,6 +18,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from repro.core.acai import AcaiProject
+from repro.core.trace import span
 
 
 def _flatten(tree) -> dict[str, Any]:
@@ -66,33 +67,42 @@ class CheckpointManager:
     def save(self, step: int, params, opt_state=None,
              extra: Optional[dict] = None, job_id: Optional[str] = None,
              input_fileset: Optional[str] = None) -> str:
-        state = {"params": params}
-        if opt_state is not None:
-            state["opt"] = opt_state
-        flat = _flatten(state)
-        buf = io.BytesIO()
-        np.savez(buf, **{k: _np_savable(v) for k, v in flat.items()})
-        manifest = {"step": step, "keys": sorted(flat),
-                    "extra": extra or {}}
-        storage = self.project.storage
-        paths = [f"/{self.fileset}/state.npz", f"/{self.fileset}/manifest.json"]
-        sid = storage.begin_session(paths, creator="trainer")
-        storage.session_put(sid, paths[0], buf.getvalue())
-        storage.session_put(sid, paths[1], json.dumps(manifest).encode())
-        fvs = storage.commit_session(sid)
-        fsv = self.project.filesets.create(
-            self.fileset, [f"{fv.path}@{fv.version}" for fv in fvs],
-            creator="trainer")
-        self.project.metadata.register(fsv.ref, kind="checkpoint",
-                                       step=step, run=self.run,
-                                       **(extra or {}))
-        if job_id is not None:
-            src = None
-            if input_fileset:
-                src = self.project.filesets.resolve(input_fileset).ref
-            self.project.provenance.add_job_edge(src=src, dst=fsv.ref,
-                                                 job_id=job_id)
-        return fsv.ref
+        with span("ckpt/save"):
+            state = {"params": params}
+            if opt_state is not None:
+                state["opt"] = opt_state
+            flat = _flatten(state)
+            with span("ckpt/fetch"):     # device-to-host copy of each leaf
+                arrays = {k: _np_savable(v) for k, v in flat.items()}
+            with span("ckpt/encode"):
+                buf = io.BytesIO()
+                np.savez(buf, **arrays)
+                del arrays
+                payload = buf.getvalue()
+            manifest = {"step": step, "keys": sorted(flat),
+                        "extra": extra or {}}
+            storage = self.project.storage
+            paths = [f"/{self.fileset}/state.npz",
+                     f"/{self.fileset}/manifest.json"]
+            with span("lake/put"):
+                sid = storage.begin_session(paths, creator="trainer")
+                storage.session_put(sid, paths[0], payload)
+                storage.session_put(sid, paths[1],
+                                    json.dumps(manifest).encode())
+                fvs = storage.commit_session(sid)
+                fsv = self.project.filesets.create(
+                    self.fileset, [f"{fv.path}@{fv.version}" for fv in fvs],
+                    creator="trainer")
+                self.project.metadata.register(fsv.ref, kind="checkpoint",
+                                               step=step, run=self.run,
+                                               **(extra or {}))
+            if job_id is not None:
+                src = None
+                if input_fileset:
+                    src = self.project.filesets.resolve(input_fileset).ref
+                self.project.provenance.add_job_edge(src=src, dst=fsv.ref,
+                                                     job_id=job_id)
+            return fsv.ref
 
     # ------------------------------------------------------------------
     def latest_step(self) -> Optional[int]:

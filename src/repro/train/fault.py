@@ -26,8 +26,11 @@ import statistics
 import time
 from typing import Callable, Optional
 
+import jax
+
 from repro.core.engine.lifecycle import (  # noqa: F401 (re-exports)
     JobPreempted, TransientJobError)
+from repro.core.trace import span
 from repro.train.checkpoints import CheckpointManager
 
 
@@ -92,6 +95,12 @@ def gang_resize_hook(job) -> Callable[[int], None]:
     return hook
 
 
+def _ready(tree) -> bool:
+    """True when every array of ``tree`` has been computed."""
+    return all(getattr(x, "is_ready", lambda: True)()
+               for x in jax.tree.leaves(tree))
+
+
 @dataclasses.dataclass
 class SupervisorReport:
     steps_run: int = 0
@@ -99,6 +108,9 @@ class SupervisorReport:
     checkpoints: int = 0
     straggler_steps: list = dataclasses.field(default_factory=list)
     final_step: int = 0
+    # seconds between successive step completions, one per step run
+    step_s: list = dataclasses.field(default_factory=list)
+    save_s: list = dataclasses.field(default_factory=list)   # per save
 
 
 class TrainSupervisor:
@@ -114,40 +126,70 @@ class TrainSupervisor:
             failure_hook: Optional[Callable[[int], None]] = None,
             time_fn: Callable[[], float] = time.perf_counter,
             ) -> tuple[dict, SupervisorReport]:
-        """state: {"params":..., "opt":..., "step": int}."""
+        """state: {"params":..., "opt":..., "step": int}.
+
+        Step ``i`` is dispatched before the host waits on step ``i-1``'s
+        metrics (``train/wait``), so one step stays queued on the device
+        while the host builds the next batch. A step's time is the
+        interval between its completion and the previous one's, seen as
+        soon as the host can tell: before the next dispatch if the step is
+        already done (the host is the bottleneck), else when the wait
+        returns. The straggler policy reads these intervals."""
         report = SupervisorReport()
-        step_times: list[float] = []
         step = state["step"]
+        pending = None           # (step, metrics) still in flight
+        last = time_fn()         # the previous completion
+
+        def complete(i, metrics, t=None):
+            nonlocal last
+            if t is None:
+                with span("train/wait"):
+                    jax.block_until_ready(metrics)
+                t = time_fn()
+            dt, last = t - last, t
+            if len(report.step_s) >= 3 and dt > self.straggler_factor * \
+                    statistics.median(report.step_s):
+                report.straggler_steps.append(i)
+            report.step_s.append(dt)
+
         while step < n_steps:
             try:
                 if failure_hook is not None:
                     failure_hook(step)       # may raise JobPreempted
-                t0 = time_fn()
-                params, opt, metrics = step_fn(state["params"],
-                                               state["opt"], batch_fn(step))
-                dt = time_fn() - t0
+                done_at = time_fn() if pending is not None and \
+                    _ready(pending[1]) else None
+                with span("train/dispatch"):
+                    params, opt, metrics = step_fn(
+                        state["params"], state["opt"], batch_fn(step))
+                if pending is not None:
+                    complete(*pending, t=done_at)
+                pending = (step, metrics)
                 state = {"params": params, "opt": opt, "step": step + 1}
                 report.steps_run += 1
-                if len(step_times) >= 3:
-                    med = statistics.median(step_times)
-                    if dt > self.straggler_factor * med:
-                        report.straggler_steps.append(step)
-                step_times.append(dt)
                 step += 1
                 if step % self.save_every == 0 or step == n_steps:
+                    complete(*pending)
+                    pending = None
+                    t0 = time.perf_counter()
                     self.ckpt.save(step, state["params"], state["opt"],
                                    extra={"loss": float(metrics["loss"])})
+                    report.save_s.append(time.perf_counter() - t0)
                     report.checkpoints += 1
+                    last = time_fn()     # the save is no step's time
             except JobPreempted as e:
                 if getattr(e, "external", False):
                     raise   # scheduler preemption: hand back the slot;
                             # the relaunch restores from the checkpoint
+                if pending is not None:
+                    complete(*pending)
+                    pending = None
                 report.restarts += 1
                 if report.restarts > self.max_restarts:
                     raise
                 restored, ck_step = self._restore_or_initial(state)
                 state = restored
                 step = ck_step
+                last = time_fn()
         report.final_step = step
         return state, report
 

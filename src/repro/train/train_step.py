@@ -87,9 +87,10 @@ def make_train_step(cfg: ArchConfig, tcfg: TrainConfig,
         if tcfg.grad_compression:
             grads, new_res = C.compress_grads_with_feedback(
                 grads, opt_state["residuals"], tcfg.grad_compression)
-        params, new_opt, opt_metrics = adamw_update(
-            ocfg, params, grads,
-            {k: v for k, v in opt_state.items() if k != "residuals"})
+        with jax.named_scope("optimizer"):
+            params, new_opt, opt_metrics = adamw_update(
+                ocfg, params, grads,
+                {k: v for k, v in opt_state.items() if k != "residuals"})
         if tcfg.grad_compression:
             new_opt["residuals"] = new_res
         metrics = dict(metrics)

@@ -1,5 +1,7 @@
 """Training substrate: optimizer, microbatching, compression, checkpoints,
 fault-tolerant supervision, data pipeline."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -185,8 +187,9 @@ def test_supervisor_restart_and_stragglers(tmp_path):
             fails.discard(step)
             raise JobPreempted(f"node died at {step}")
 
-    # time_fn is called twice per step; entry 9 is the *within-step* delta
-    # of step 4 -> one straggler step
+    # time_fn is read at the start, at each step's completion and after
+    # each save; the tenth reading ends one step's interval 0.5 s after
+    # the previous completion -> one straggler step
     clock = iter(np.concatenate([np.ones(9) * 0.01, [0.5],
                                  np.ones(100) * 0.01]).cumsum())
     state, report = sup.run(step_fn, {"params": params, "opt": opt,
@@ -200,6 +203,28 @@ def test_supervisor_restart_and_stragglers(tmp_path):
     assert report.steps_run == 20 + (12 - 10)
     assert report.checkpoints >= 4
     assert len(report.straggler_steps) >= 1
+
+
+def test_supervisor_times_each_step_and_flags_the_slow_one(tmp_path):
+    sup = TrainSupervisor(CheckpointManager(AcaiProject("p", tmp_path),
+                                            "runT"), save_every=100)
+    slow, n_steps = 6, 10
+
+    def step_fn(params, opt, batch):
+        time.sleep(0.4 if batch["i"] == slow else 0.02)
+        return params, opt, {"loss": jnp.zeros(())}
+
+    params = {"w": jnp.zeros(2)}
+    state, report = sup.run(step_fn, {"params": params,
+                                      "opt": init_opt_state(params),
+                                      "step": 0},
+                            n_steps=n_steps, batch_fn=lambda i: {"i": i})
+    assert state["step"] == n_steps
+    assert len(report.step_s) == n_steps
+    assert slow in report.straggler_steps
+    assert slow - 1 not in report.straggler_steps
+    assert report.step_s[slow] >= 0.4
+    assert len(report.save_s) == report.checkpoints == 1
 
 
 def test_pipeline_determinism_and_sharding():
