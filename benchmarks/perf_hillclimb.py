@@ -101,11 +101,6 @@ def cell_C():
     measure("C0_baseline_fsdp", "qwen3-32b", "decode_32k")
     measure("C2_resident_tp_only", "qwen3-32b", "decode_32k",
             serve_layout="resident")
-    import repro.models.blocks as B
-    B.CACHE_INSERT_IMPL = "scatter"
-    measure("C3_scatter_insert", "qwen3-32b", "decode_32k",
-            serve_layout="resident")
-    B.CACHE_INSERT_IMPL = "onehot"
 
 
 if __name__ == "__main__":

@@ -29,8 +29,8 @@ def advice(r: dict) -> str:
                 "— §Perf A6 shows the opposite wall")
     if dom == "memory":
         if kind == "decode":
-            return ("cache-insert aliasing + flash-decode kernel remove the "
-                    "rewrite and score traffic (§Perf C3 note)")
+            return ("a flash-decode kernel keeps the score traffic in VMEM "
+                    "(ROADMAP A3)")
         if f.get("useful_flops_ratio", 1) < 0.6:
             return ("dots-saveable remat + Pallas flash attention cut "
                     "recompute and score HBM traffic — §Perf A1/A8")

@@ -45,7 +45,8 @@ def serve(cfg: ArchConfig, params, prompts: list[list[int]], *,
                          f"buffer_len is {buffer_len}")
     with span("serve/init"):
         states = T.init_decode_state(cfg, slots, buffer_len)
-        step = jax.jit(make_serve_step(cfg, buffer_len))
+        # states donated: the step writes the new K/V rows in place
+        step = jax.jit(make_serve_step(cfg, buffer_len), donate_argnums=(1,))
 
     slot_req = [-1] * slots
     slot_prompt: list[list[int]] = [[] for _ in range(slots)]

@@ -176,25 +176,36 @@ def full_causal_attention(q, k, v):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len):
-    """Single-token decode: q (B, 1, H, D) vs UNEXPANDED GQA cache
-    (B, Skv, KV, D); first ``cache_len`` positions valid; softmax fp32.
+def decode_attention(q, k_new, v_new, k_cache, v_cache, cache_len):
+    """One new token attending to its slot's cache and to itself.
+
+    q (B, 1, H, D); the token's own k_new, v_new (B, 1, KV, D); the cache
+    (B, KV, S, D), read in place, its first ``cache_len`` positions valid.
+    One fp32 softmax over the cache and the new token: the same as writing
+    the row at ``cache_len`` and attending over ``cache_len + 1``
+    positions, without the write.
 
     Grouped einsums instead of jnp.repeat head expansion: the repeat op
-    breaks GSPMD partitioning of a sequence-sharded cache (it fell back to
-    full 17 GB cache all-gathers per layer on qwen3-32b decode — §Perf C).
+    breaks GSPMD partitioning of a sequence-sharded cache.
     """
     b, _, h, d = q.shape
-    skv, kv = k_cache.shape[1], k_cache.shape[2]
-    g = h // kv
-    qg = q.reshape(b, 1, kv, g, d)
-    sc = jnp.einsum("bqkgd,bskd->bkgqs", qg, k_cache,
-                    preferred_element_type=jnp.float32) * d ** -0.5
-    valid = jnp.arange(skv)[None, :] < cache_len[:, None]    # (B, Skv)
-    sc = jnp.where(valid[:, None, None, None, :], sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1).astype(v_cache.dtype)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", p, v_cache)
-    return o.reshape(b, 1, h, d)
+    kv, skv = k_cache.shape[1], k_cache.shape[2]
+    scale = d ** -0.5
+    qg = q.reshape(b, kv, h // kv, d)
+    sc = jnp.einsum("bkgd,bksd->bkgs", qg, k_cache,
+                    preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(skv)[None, :] < cache_len[:, None]    # (B, S)
+    sc = jnp.where(valid[:, None, None, :], sc, -jnp.inf)
+    sn = jnp.einsum("bkgd,bkd->bkg", qg, k_new[:, 0],
+                    preferred_element_type=jnp.float32) * scale
+    m = jnp.maximum(sc.max(-1), sn)
+    pc = jnp.exp(sc - m[..., None])
+    pn = jnp.exp(sn - m)
+    l = pc.sum(-1) + pn
+    o = jnp.einsum("bkgs,bksd->bkgd", (pc / l[..., None]).astype(v_cache.dtype),
+                   v_cache, preferred_element_type=jnp.float32)
+    o = o + (pn / l)[..., None] * v_new[:, 0, :, None, :].astype(jnp.float32)
+    return o.reshape(b, 1, h, d).astype(q.dtype)
 
 
 def attention_block(p, x, cfg: ArchConfig, *, rope=None, positions=None,
@@ -202,8 +213,10 @@ def attention_block(p, x, cfg: ArchConfig, *, rope=None, positions=None,
                     causal=True, attn_impl="xla", seq_axis=None):
     """Full attention sub-block: proj -> rope -> (qk-norm) -> attn -> out proj.
 
-    kv_cache: None for train/prefill; (k, v) of shape (B, Skv, KV, D) for
-    decode (returns updated cache). kv_src: cross-attention source states.
+    kv_cache: None for train/prefill; for decode this layer's (k, v) cache
+    of shape (B, KV, S, D), read only: the block returns the token's new
+    (B, 1, KV, D) K and V rows for the caller to write at ``cache_len``.
+    kv_src: cross-attention source states.
     """
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
@@ -223,15 +236,14 @@ def attention_block(p, x, cfg: ArchConfig, *, rope=None, positions=None,
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
 
-    new_cache = None
+    new_rows = None
     if kv_cache is not None:             # decode step
         kc, vc = kv_cache
-        idx = cache_len                   # (B,) insert position
-        kc = _cache_insert(kc, k, idx)
-        vc = _cache_insert(vc, v, idx)
-        new_cache = (kc, vc)
-        o = decode_attention(q, kc.astype(cd), vc.astype(cd),
-                             cache_len + 1)
+        # the token attends to its rows as the cache will hold them
+        k_row, v_row = k.astype(kc.dtype), v.astype(vc.dtype)
+        new_rows = (k_row, v_row)
+        o = decode_attention(q, k_row.astype(cd), v_row.astype(cd),
+                             kc.astype(cd), vc.astype(cd), cache_len)
     elif kv_src is not None:             # cross attention (not causal)
         kq = _gqa_expand(k, cfg.n_heads)
         vq = _gqa_expand(v, cfg.n_heads)
@@ -260,29 +272,7 @@ def attention_block(p, x, cfg: ArchConfig, *, rope=None, positions=None,
         else:
             o = chunked_causal_attention(q, kq, vq)
     out = o.reshape(b, s, cfg.n_heads * hd) @ p["wo"].astype(cd)
-    return out, new_cache
-
-
-CACHE_INSERT_IMPL = "onehot"   # onehot | scatter  (§Perf C3)
-
-
-def _cache_insert(cache, new, idx):
-    """Insert new (B, 1, KV, D) at per-batch position idx into
-    (B, S, KV, D).
-
-    "onehot" rewrites the whole cache (read+write of every byte — simple,
-    always partitionable); "scatter" writes only B rows via jnp scatter
-    (cheaper HBM traffic IF GSPMD partitions it against the sharded seq
-    dim — measured per cell in §Perf)."""
-    with jax.named_scope("cache_insert"):
-        if CACHE_INSERT_IMPL == "scatter":
-            b = cache.shape[0]
-            return cache.at[jnp.arange(b), idx].set(
-                new[:, 0].astype(cache.dtype), mode="drop")
-        s = cache.shape[1]
-        onehot = (jnp.arange(s)[None, :] == idx[:, None]).astype(cache.dtype)
-        return cache * (1 - onehot)[:, :, None, None] + \
-            onehot[:, :, None, None] * new.astype(cache.dtype)
+    return out, new_rows
 
 
 # ---------------------------------------------------------------------------

@@ -8,8 +8,11 @@ Layouts (keeps HLO size ~one layer body regardless of depth):
             -> vlm   (4 dense + 1 cross-attn) x 8
             -> hybrid(5 mamba + 1 *shared* attn block) x 13 + 3 mamba
 
-Decode state is a pytree with the same stacking as the params, threaded
-through the scans as xs/ys.
+Decode state is a pytree with the same stacking as the params. Attention
+caches, (B, KV, S, D) per layer, go into the scans as xs and are only read
+there: each layer emits its new K/V rows as ys, and one scatter after the
+scans writes them in place (``_write_rows``). Recurrent states (rwkv,
+mamba) are rewritten whole each step and go through the scans as xs/ys.
 """
 from __future__ import annotations
 
@@ -77,17 +80,21 @@ def init_layer(block: str, cfg: ArchConfig, key):
     raise ValueError(block)
 
 
+ATTN_BLOCKS = ("dense", "moe", "shared_attn")
+
+
 def layer_fwd(block: str, p, x, cfg: ArchConfig, ctx: dict,
               state=None, collect_kv: bool = False):
-    """Returns (x, new_state, aux, kv_out)."""
+    """Returns (x, new_state, aux, kv_out). In decode, an attention block's
+    new_state is its new (k, v) rows, a cross-attention block's None."""
     aux = jnp.zeros((), jnp.float32)
     kv_out = None
     decode = ctx["mode"] == "decode"
-    if block in ("dense", "moe", "shared_attn"):
+    if block in ATTN_BLOCKS:
         h = B.apply_norm(p["ln1"], x, cfg)
         kv_cache = state if decode else None
         with jax.named_scope("attention"):
-            o, new_cache = B.attention_block(
+            o, new_rows = B.attention_block(
                 p["attn"], h, cfg, rope=ctx.get("rope"),
                 positions=ctx.get("positions"),
                 kv_cache=kv_cache, cache_len=ctx.get("cache_len"),
@@ -99,37 +106,32 @@ def layer_fwd(block: str, p, x, cfg: ArchConfig, ctx: dict,
         else:
             y = B.mlp_block(p["mlp"], h)
         x = x + y
-        new_state = new_cache if decode else None
         x = constrain(x, ("batch", None, None))
-        return x, new_state, aux, kv_out
+        return x, new_rows, aux, kv_out
     if block == "cross_attn":
         h = B.apply_norm(p["ln1"], x, cfg)
         if decode:
-            kv, vv = state          # precomputed vision K/V
+            kv, vv = state          # precomputed vision K/V (B, KV, Nv, D)
             hd = cfg.resolved_head_dim
-            b_, s_, _ = h.shape
+            b_, s_, _ = h.shape     # s_ == 1
             q = (h @ p["attn"]["wq"].astype(h.dtype)).reshape(
-                b_, s_, cfg.n_heads, hd)
+                b_, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, hd)
             if cfg.qk_norm:
                 q = B.rms_head_norm(q, p["attn"]["q_norm"].astype(h.dtype))
-            kq = B._gqa_expand(kv.astype(h.dtype), cfg.n_heads)
-            vq = B._gqa_expand(vv.astype(h.dtype), cfg.n_heads)
-            sc = jnp.einsum("bqhd,bkhd->bhqk", q, kq,
+            sc = jnp.einsum("bkgd,bksd->bkgs", q, kv.astype(h.dtype),
                             preferred_element_type=jnp.float32) * hd ** -0.5
             pr = jax.nn.softmax(sc, -1).astype(h.dtype)
-            o = jnp.einsum("bhqk,bkhd->bqhd", pr, vq)
+            o = jnp.einsum("bkgs,bksd->bkgd", pr, vv.astype(h.dtype))
             o = o.reshape(b_, s_, cfg.n_heads * hd) @ \
                 p["attn"]["wo"].astype(h.dtype)
-            new_state = state
         else:
             o, _ = B.attention_block(p["attn"], h, cfg,
                                      kv_src=ctx["vision"].astype(h.dtype))
-            new_state = None
         x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * o
         h = B.apply_norm(p["ln2"], x, cfg)
         x = x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * B.mlp_block(p["mlp"], h)
         x = constrain(x, ("batch", None, None))
-        return x, new_state, aux, None
+        return x, None, aux, None
     if block == "rwkv":
         h = B.apply_norm(p["ln1"], x, cfg)
         if decode:
@@ -202,18 +204,20 @@ def _maybe_remat(fn, ctx):
 
 def _scan_layers(block: str, stacked, x, cfg, ctx, states=None,
                  collect_kv=False):
-    """Scan homogeneous stacked layers. Returns (x, aux, new_states, kvs)."""
+    """Scan homogeneous stacked layers. Returns (x, aux, outs, kvs); in
+    decode ``outs`` stacks each layer's ``layer_fwd`` new_state, for
+    ``_commit``."""
     decode = ctx["mode"] == "decode"
 
     if decode:
         def body(carry, xs):
             x, aux = carry
             p, st = xs
-            x, new_st, a, _ = layer_fwd(block, p, x, cfg, ctx, st)
-            return (x, aux + a), new_st
-        (x, aux), new_states = jax.lax.scan(
+            x, out, a, _ = layer_fwd(block, p, x, cfg, ctx, st)
+            return (x, aux + a), out
+        (x, aux), outs = jax.lax.scan(
             body, (x, jnp.zeros((), jnp.float32)), (stacked, states))
-        return x, aux, new_states, None
+        return x, aux, outs, None
 
     def body(carry, p):
         x, aux = carry
@@ -225,16 +229,47 @@ def _scan_layers(block: str, stacked, x, cfg, ctx, states=None,
     return x, aux, None, kvs
 
 
+def _write_rows(cache, rows, cache_len):
+    """Write rows (*stack, B, 1, KV, D) into the stacked cache
+    (*stack, B, KV, S, D) at position ``cache_len[b]``.
+
+    The scatter is indexed on every axis but D, so its update window is one
+    row: with the cache donated XLA writes it in place and copies nothing
+    cache-sized. A position past the buffer is dropped."""
+    rows = rows[..., 0, :, :]
+    shape = rows.shape[:-1]                      # (*stack, B, KV)
+    idx = [jax.lax.broadcasted_iota(jnp.int32, shape, i)
+           for i in range(len(shape))]
+    pos = jnp.broadcast_to(cache_len[:, None], shape)
+    return cache.at[(*idx, pos)].set(rows.astype(cache.dtype), mode="drop",
+                                     unique_indices=True)
+
+
+def _commit(block: str, states, outs, cache_len):
+    """A stack's decode state after the step, from the scans' ``outs``."""
+    if block in ATTN_BLOCKS:
+        with jax.named_scope("cache_insert"):
+            return tuple(_write_rows(c, r, cache_len)
+                         for c, r in zip(states, outs))
+    if block == "cross_attn":
+        return states           # vision K/V, fixed for the request
+    return outs                 # recurrent states, rewritten whole
+
+
 def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
     """Run all layers. states: decode-state pytree or None.
 
     Returns (x, aux, new_states)."""
     layout = build_layout(cfg)
+    cache_len = ctx.get("cache_len")
     if layout["kind"] == "uniform":
-        x, aux, new_states, _ = _scan_layers(
+        x, aux, outs, _ = _scan_layers(
             layout["block"], params["layers"], x, cfg, ctx,
             None if states is None else states["layers"])
-        return x, aux, (None if states is None else {"layers": new_states})
+        if states is None:
+            return x, aux, None
+        return x, aux, {"layers": _commit(layout["block"], states["layers"],
+                                          outs, cache_len)}
 
     periods = layout["periods"]
     inner_block = layout["inner_block"]
@@ -251,11 +286,11 @@ def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
             else:
                 inner_p, (inner_st, single_st) = xs
                 single_p = shared_p
-            x, a1, new_inner_st, _ = _scan_layers(
+            x, a1, inner_out, _ = _scan_layers(
                 inner_block, inner_p, x, cfg, ctx, inner_st)
-            x, new_single_st, a2, _ = layer_fwd(
+            x, single_out, a2, _ = layer_fwd(
                 single_block, single_p, x, cfg, ctx, single_st)
-            return (x, aux + a1 + a2), (new_inner_st, new_single_st)
+            return (x, aux + a1 + a2), (inner_out, single_out)
 
         if single_block == "cross_attn":
             xs = ((params["layers"]["inner"], params["layers"]["single"]),
@@ -263,16 +298,20 @@ def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
         else:
             xs = (params["layers"]["inner"],
                   (states["inner"], states["single"]))
-        (x, aux), new_sts = jax.lax.scan(outer, (x, aux0), xs)
-        new_states = {"inner": new_sts[0], "single": new_sts[1]}
+        (x, aux), (inner_out, single_out) = jax.lax.scan(outer, (x, aux0), xs)
+        new_states = {
+            "inner": _commit(inner_block, states["inner"], inner_out,
+                             cache_len),
+            "single": _commit(single_block, states["single"], single_out,
+                              cache_len),
+            "trailing": states["trailing"]}
         if layout["trailing"]:
-            x, a3, new_tr, _ = _scan_layers(
+            x, a3, tr_out, _ = _scan_layers(
                 inner_block, params["layers"]["trailing"], x, cfg, ctx,
                 states["trailing"])
             aux = aux + a3
-            new_states["trailing"] = new_tr
-        else:
-            new_states["trailing"] = states["trailing"]
+            new_states["trailing"] = _commit(inner_block, states["trailing"],
+                                             tr_out, cache_len)
         return x, aux, new_states
 
     def outer(carry, xs):
@@ -308,7 +347,7 @@ def init_decode_state(cfg: ArchConfig, batch: int, buffer_len: int,
     layout = build_layout(cfg)
 
     def attn_state():
-        shape = (batch, buffer_len, cfg.n_kv_heads, hd)
+        shape = (batch, cfg.n_kv_heads, buffer_len, hd)
         return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
     def rwkv_state():
@@ -332,7 +371,8 @@ def init_decode_state(cfg: ArchConfig, batch: int, buffer_len: int,
             b_, nv, cfg.n_kv_heads, hd)
         v = (vision @ single_p["attn"]["wv"].astype(vision.dtype)).reshape(
             b_, nv, cfg.n_kv_heads, hd)
-        return (k.astype(dtype), v.astype(dtype))
+        return (jnp.swapaxes(k, 1, 2).astype(dtype),
+                jnp.swapaxes(v, 1, 2).astype(dtype))
 
     def stack_states(maker, n):
         one = maker()

@@ -49,7 +49,7 @@ def greedy_generate(cfg: ArchConfig, params, prompt, max_new: int,
     buf = prompt.shape[1] + max_new
     states = T.init_decode_state(cfg, b, buf, vision=vision, params=params)
     cache_len = jnp.zeros((b,), jnp.int32)
-    step = jax.jit(make_serve_step(cfg, buf))
+    step = jax.jit(make_serve_step(cfg, buf), donate_argnums=(1,))
     toks = prompt
     out = []
     cur = toks[:, :1]

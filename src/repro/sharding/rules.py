@@ -196,25 +196,25 @@ def decode_state_specs(cfg, global_batch: int,
     tp_size = int(mesh.shape[tp]) if tp else 1
 
     def attn_spec():
-        # (stack..., B, S, KV, D)
+        # (stack..., B, KV, S, D)
         if layout == "resident" and batch_axes and tp is not None:
-            return (None, tuple(batch_axes) + (tp,), None, None)
+            return (None, None, tuple(batch_axes) + (tp,), None)
         seq_ax = None
         if b_ax is None and batch_axes:
             # batch too small to shard -> the sequence takes the data axis
             seq_ax = batch_axes if len(batch_axes) > 1 else batch_axes[0]
         if cfg.n_kv_heads % tp_size == 0 and tp_size > 1:
-            return (b_ax, seq_ax, tp, None)
+            return (b_ax, tp, seq_ax, None)
         if seq_ax is not None and tp is not None:
-            return (b_ax, tuple(batch_axes) + (tp,), None, None)
-        return (b_ax, tp, None, None)       # seq over model
+            return (b_ax, None, tuple(batch_axes) + (tp,), None)
+        return (b_ax, None, tp, None)       # seq over model
 
     def stackP(nstack, core):
         return P(*((None,) * nstack + tuple(core)))
 
-    layout = build_layout(cfg)
-    if layout["kind"] == "uniform":
-        if layout["block"] == "rwkv":
+    stack = build_layout(cfg)
+    if stack["kind"] == "uniform":
+        if stack["block"] == "rwkv":
             st = (stackP(1, (b_ax, tp, None, None)),      # wkv (B,H,K,V)
                   stackP(1, (b_ax, None, None)),          # tm last token
                   stackP(1, (b_ax, None, None)))          # cm last token
@@ -223,7 +223,7 @@ def decode_state_specs(cfg, global_batch: int,
         return {"layers": (stackP(1, core), stackP(1, core))}
 
     # periodic
-    if layout["inner_block"] == "mamba":
+    if stack["inner_block"] == "mamba":
         inner = (stackP(2, (b_ax, tp, None, None)),       # ssm (B,H,N,P)
                  stackP(2, (b_ax, None, tp)))             # conv (B,W-1,C)
         trailing = (stackP(1, (b_ax, tp, None, None)),
@@ -233,7 +233,7 @@ def decode_state_specs(cfg, global_batch: int,
         inner = (stackP(2, core), stackP(2, core))
         trailing = (stackP(1, core), stackP(1, core))
     core = attn_spec()
-    if layout["single_block"] == "cross_attn":
+    if stack["single_block"] == "cross_attn":
         single = (stackP(1, (b_ax, None, None, None)),
                   stackP(1, (b_ax, None, None, None)))
     else:

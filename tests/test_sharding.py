@@ -108,6 +108,15 @@ def test_decode_state_specs_match(arch, shape_name, layout):
         pytest.skip("vlm state init needs vision/params; covered by dryrun")
     jax.tree.map(check, state_shapes, specs,
                  is_leaf=lambda x: isinstance(x, P) or hasattr(x, "shape"))
+    lay = T.build_layout(cfg)
+    if lay["kind"] == "uniform" and lay["block"] in T.ATTN_BLOCKS:
+        # caches are (L, B, KV, S, D): KV heads take only the model axis,
+        # the resident layout shards the sequence over data x model
+        k_shape, k_spec = state_shapes["layers"][0].shape, specs["layers"][0]
+        assert k_shape[-2] == shape.seq_len
+        assert k_spec[-3] in (None, "model"), k_spec
+        if layout == "resident":
+            assert k_spec[-2] == ("data", "model"), k_spec
 
 
 @pytest.mark.parametrize("gb,expected_sharded", [(256, True), (1, False)])

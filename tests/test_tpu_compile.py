@@ -3,7 +3,9 @@
 Nothing runs: the TPU compiler refuses here what the chip would refuse
 (block tiling, VMEM, a program that does not fit HBM). Covered: the four
 Pallas kernels at real widths, the olmo-1b serve step at full width and
-depth, and the olmo-1b train step sharded over the 2x2 mesh.
+depth, the generation cell's serve step writing its donated cache in
+place, the olmo-1b decode step with its cache sharded over the 2x2 mesh,
+and the olmo-1b train step sharded over the 2x2 mesh.
 
 The topology is described inside a module fixture, never at import, so
 only the worker that runs this file loads the TPU compiler.
@@ -113,6 +115,113 @@ def test_olmo_serve_step_compiles_and_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes)
     assert used < HBM_BYTES, used
+
+
+def test_generation_step_writes_its_donated_cache_in_place(one_chip):
+    """mistral-nemo-12b's decode step at the generation cell's shapes (8
+    layers, 32 slots, a 2,048-token buffer, bfloat16 weights), with the
+    states donated as ``serve`` donates them: the output cache is the
+    input's buffer, and no cache-sized temporary is left (one layer's K
+    cache is 134 MB)."""
+    from repro.models import model as M
+    from repro.models import transformer as T
+    from repro.serve.decode import make_serve_step
+
+    cfg = dataclasses.replace(get_arch("mistral-nemo-12b"), n_layers=8)
+    slots, buf = 32, 2048
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, dtype or a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(M.init_params, cfg),
+                                    jax.random.PRNGKey(0)), jnp.bfloat16)
+    states = on_chip(jax.eval_shape(functools.partial(
+        T.init_decode_state, cfg, slots, buf)))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+                     "cache_len": jax.ShapeDtypeStruct((slots,),
+                                                       jnp.int32)})
+    compiled = jax.jit(make_serve_step(cfg, buf), donate_argnums=(1,)).lower(
+        params, states, batch).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(states))
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < 64e6, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("layout", ["fsdp", "resident"])
+def test_sharded_olmo_decode_gathers_no_cache_on_2x2(topo, layout):
+    """olmo-1b's decode step with its cache sharded by
+    ``decode_state_specs`` over the 2x2 mesh, for one request, too few to
+    shard: fsdp puts the KV heads on "model" and the sequence on "data",
+    resident the sequence on data x model. No collective may rebuild any
+    part of a cache, across the sequence or across the heads (GSPMD
+    gathers by all-gather, or by an all-reduce of a padded buffer): none
+    may return an array with the sequence extent of the buffer or of a
+    shard of it."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.models import model as M
+    from repro.models import transformer as T
+    from repro.serve.decode import make_serve_step
+    from repro.sharding import rules as SR
+
+    cfg = get_arch("olmo-1b")
+    batch_size, buf = 1, 12288
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    rules = SR.AxisRules.for_mesh(mesh)
+    resident = layout == "resident"
+
+    def named(specs):
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    params = jax.eval_shape(functools.partial(M.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    if resident:        # as the dry run serves it: bfloat16 weights
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16), params)
+    states = jax.eval_shape(functools.partial(
+        T.init_decode_state, cfg, batch_size, buf))
+    batch = {"tokens": jax.ShapeDtypeStruct((batch_size, 1), jnp.int32),
+             "cache_len": jax.ShapeDtypeStruct((batch_size,), jnp.int32)}
+    SR.set_rules(rules)
+    try:
+        sspecs = SR.decode_state_specs(cfg, batch_size, rules, layout=layout)
+        assert sspecs["layers"][0] == {
+            "fsdp": P(None, None, "model", "data", None),
+            "resident": P(None, None, None, ("data", "model"), None),
+        }[layout]
+        step = jax.jit(
+            make_serve_step(cfg, buf),
+            in_shardings=(named(SR.param_specs(cfg, rules, fsdp=not resident,
+                                               param_shapes=params)),
+                          named(sspecs),
+                          named(SR.batch_specs(cfg, "decode", batch_size,
+                                               rules, layout=layout))),
+            out_shardings=(None, named(sspecs), None),
+            donate_argnums=(1,))
+        compiled = step.lower(params, states, batch).compile()
+    finally:
+        SR.set_rules(None)
+    # a gathered piece of a cache carries the buffer's sequence extent or
+    # a shard's; the buffer is chosen so that no weight has those extents
+    seq = {buf, buf // 2, buf // 4}
+    assert not seq & {d for a in jax.tree.leaves(params) for d in a.shape}
+    collective = re.compile(r"= (.*?) (all-gather|all-reduce|all-to-all|"
+                            r"collective-permute|reduce-scatter)"
+                            r"(-start|-done)?\(")
+    for line in compiled.as_text().splitlines():
+        m = collective.search(line)
+        if m:
+            dims = {int(d) for shape in re.findall(r"\[([\d,]+)\]",
+                                                   m.group(1))
+                    for d in shape.split(",")}
+            assert not dims & seq, line
 
 
 def test_sharded_olmo_train_step_compiles_on_2x2(topo):
