@@ -15,22 +15,35 @@ _REGISTRY: dict[str, "ArchConfig"] = {}
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    """Routed experts, dropless: every (token, choice) pair routed to an
+    expert held here is computed, however uneven the load."""
+    n_experts: int                 # router outputs (all experts)
     top_k: int
     d_ff_expert: int
     n_shared_experts: int = 0
     d_ff_shared: int = 0
-    capacity_factor: float = 1.25
-    router_aux_coef: float = 0.01
+    # router: "softmax" or "sigmoid" scores; the top-k picked on the
+    # scores plus a per-layer correction bias when ``bias_std`` is set
+    # (DeepSeek-V3 noaux_tc: the bias moves selection, never the weights;
+    # the optimizer does not train it); weights renormalised over the
+    # picked k, then scaled by ``routed_scaling``
+    score_func: str = "softmax"
+    routed_scaling: float = 1.0
+    bias_std: Optional[float] = None
+    # balance loss aux_coef * sum_i f_i P_i (DeepSeek-V3 eqs. 17-20), per
+    # sequence when ``seq_aux``, else over the whole batch
+    aux_coef: float = 0.01
+    seq_aux: bool = False
+    # the experts held here: ids first_held .. first_held + n_held - 1
+    # (0 = all); the router still routes over all n_experts
+    n_held: int = 0
+    first_held: int = 0
     # every k-th layer is MoE (1 = all layers)
     moe_every: int = 1
-    # independent routing groups (aligned with data shards so dispatch
-    # scatter/gather stays device-local); capacity is per group
-    n_dispatch_groups: int = 16
-    # compute the shared expert INSIDE the EP shard_map on its model-axis
-    # ff slice so its partial sums ride the EP psum (one collective
-    # instead of two) — §Perf cell B
-    fuse_shared: bool = False
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -85,12 +98,30 @@ class ArchConfig:
     # audio (musicgen): number of codebooks (input (B,S,K), K lm heads)
     n_codebooks: int = 0
     norm_eps: float = 1e-5
+    # latent attention (MLA, DeepSeek-V2): K/V from a normed latent of
+    # kv_lora_rank; q/k heads of qk_nope + qk_rope, v heads of v_head_dim
+    # (0 = plain attention)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading layers that are dense in an MoE model
+    first_k_dense: int = 0
     source: str = ""
 
     # ---- derived -----------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of the rotated part of each q/k head."""
+        return self.qk_rope_head_dim if self.mla else self.resolved_head_dim
 
     @property
     def attention_free(self) -> bool:
@@ -117,8 +148,8 @@ class ArchConfig:
             elif self.family == "vlm" and self.cross_attn_every and (
                     i % self.cross_attn_every == self.cross_attn_every - 1):
                 kinds.append("cross_attn")
-            elif self.moe is not None and (i % self.moe.moe_every
-                                           == self.moe.moe_every - 1):
+            elif self.moe is not None and i >= self.first_k_dense and (
+                    i % self.moe.moe_every == self.moe.moe_every - 1):
                 kinds.append("moe")
             else:
                 kinds.append("dense")
@@ -148,7 +179,7 @@ class ArchConfig:
             if kind == "moe":
                 m = self.moe
                 act = self._attn_params() + 2 * d
-                act += m.top_k * 3 * d * m.d_ff_expert
+                act += min(m.top_k, m.held) * 3 * d * m.d_ff_expert
                 act += m.n_shared_experts * 3 * d * m.d_ff_shared
                 act += d * m.n_experts  # router
                 total += act
@@ -157,9 +188,14 @@ class ArchConfig:
         return total + d
 
     def _attn_params(self) -> int:
-        d, hd = self.d_model, self.resolved_head_dim
-        p = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd \
-            + self.n_heads * hd * d
+        d, hd, h = self.d_model, self.resolved_head_dim, self.n_heads
+        if self.mla:
+            r, qk = self.kv_lora_rank, self.qk_nope_head_dim
+            return (d * h * (qk + self.qk_rope_head_dim)
+                    + d * (r + self.qk_rope_head_dim) + r
+                    + r * h * (qk + self.v_head_dim)
+                    + h * self.v_head_dim * d)
+        p = d * h * hd + 2 * d * self.n_kv_heads * hd + h * hd * d
         if self.qk_norm:
             p += 2 * hd
         return p
@@ -171,7 +207,8 @@ class ArchConfig:
         if kind == "moe":
             m = self.moe
             p = self._attn_params() + 2 * d + d * m.n_experts
-            p += m.n_experts * 3 * d * m.d_ff_expert
+            p += m.n_experts if m.bias_std is not None else 0
+            p += m.held * 3 * d * m.d_ff_expert
             p += m.n_shared_experts * 3 * d * m.d_ff_shared
             return p
         if kind == "rwkv":
@@ -230,8 +267,11 @@ class ArchConfig:
         if self.moe:
             kw["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=min(self.moe.top_k, 2),
-                d_ff_expert=64, n_dispatch_groups=1,
+                d_ff_expert=64, n_held=0, first_held=0,
                 d_ff_shared=64 if self.moe.n_shared_experts else 0)
+        if self.mla:
+            kw.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                      qk_rope_head_dim=8, v_head_dim=16)
         if self.mamba:
             kw["mamba"] = dataclasses.replace(
                 self.mamba, d_state=16, head_dim=16, chunk=16)
@@ -270,4 +310,4 @@ def _load_all() -> None:
     from repro.configs import (  # noqa: F401
         qwen3_32b, qwen3_8b, mistral_nemo_12b, olmo_1b, olmoe_1b_7b,
         llama4_scout, rwkv6_7b, llama32_vision_11b, zamba2_7b,
-        musicgen_large)
+        musicgen_large, moonlight_16b_a3b)

@@ -32,6 +32,8 @@ def applicable(arch: ArchConfig, shape: ShapeConfig) -> tuple[bool, str]:
     if shape.name == "long_500k" and not arch.subquadratic:
         return False, ("pure full-attention arch: 500k-token KV decode is "
                        "quadratic-prefill territory; skipped per assignment")
+    if shape.kind == "decode" and arch.mla:
+        return False, "latent attention (MLA): no latent K/V cache yet"
     return True, ""
 
 

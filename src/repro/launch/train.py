@@ -115,9 +115,11 @@ def train(cfg, project: AcaiProject, run: str, *, steps: int, seq_len: int,
                                               "step": 0}, steps, batch_fn)
     if report.step_s:
         # the engine's log parser attaches these to the job's metadata
+        counters = "".join(f",{k}={v}"
+                           for k, v in report.counter_means().items())
         print(f"[[acai:step_s_median={statistics.median(report.step_s)},"
               f"straggler_steps={len(report.straggler_steps)},"
-              f"ckpt_save_s={sum(report.save_s)}]]")
+              f"ckpt_save_s={sum(report.save_s)}{counters}]]")
     return TrainResult(state, report, [float(x) for x in losses], ckpt)
 
 

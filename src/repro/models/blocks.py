@@ -1,11 +1,13 @@
 """Core transformer blocks: norms, RoPE, GQA attention (chunked online-softmax
-XLA path + pluggable Pallas path), SwiGLU MLP, GShard-style MoE.
+XLA path + pluggable Pallas path), latent attention (MLA), SwiGLU MLP, and
+a dropless MoE whose expert products are one grouped matrix product.
 
 All blocks are pure functions over param pytrees (dicts of jnp arrays).
 Params live in fp32; forward casts to ``compute_dtype`` at block entry.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -119,54 +121,39 @@ def _gqa_expand(k, n_heads):
 
 def chunked_causal_attention(q, k, v, *, chunk: int = 512,
                              logit_dtype=jnp.float32):
-    """Online-softmax causal attention, scanning KV chunks (flash-style,
-    O(S*chunk) live memory). q,k,v: (B, S, H, D) (kv already GQA-expanded).
-
-    Baseline schedule computes every (q, kv-chunk) pair and masks above the
-    diagonal (2x score-FLOP waste vs causal optimum; see EXPERIMENTS.md §Perf
-    for the tournament schedule that removes it on the hot cells).
-    """
+    """Causal attention in query chunks, O(S*chunk) live memory: chunk i
+    takes an exact fp32 softmax over the keys up to its own end, so the
+    scores above the diagonal's chunks are never computed (half of S x S
+    in all). Differentiated, each chunk's scores are recomputed rather
+    than kept. q, k: (B, S, H, D), v: (B, S, H, Dv) (kv already
+    GQA-expanded); the scores are scaled by D^-1/2 and materialize at
+    ``logit_dtype`` (bf16 under §Perf A8)."""
     b, s, h, d = q.shape
-    scale = d ** -0.5
     nc = max(s // chunk, 1)
     chunk = s // nc
-    qf = jnp.swapaxes(q, 1, 2) * scale            # (B, H, S, D)
-    kc = jnp.swapaxes(k, 1, 2).reshape(b, h, nc, chunk, d)
-    vc = jnp.swapaxes(v, 1, 2).reshape(b, h, nc, chunk, d)
-    kc = jnp.moveaxis(kc, 2, 0)                   # (nc, B, H, C, D)
-    vc = jnp.moveaxis(vc, 2, 0)
-    q_pos = jnp.arange(s)
+    scale = d ** -0.5
 
-    def body(carry, xs):
-        m, l, o = carry
-        kb, vb, idx = xs
-        # score blocks materialize at logit_dtype (fp32 default; bf16 under
-        # §Perf A8 — running stats below are ALWAYS fp32)
-        sc = jnp.einsum("bhqd,bhkd->bhqk", qf, kb,
+    @functools.partial(jax.checkpoint, static_argnums=(3,))
+    def block(qb, kb, vb, i):
+        sc = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
                         preferred_element_type=logit_dtype)
-        k_pos = idx * chunk + jnp.arange(chunk)
-        mask = q_pos[:, None] >= k_pos[None, :]
-        scf = jnp.where(mask[None, None], sc.astype(jnp.float32), -jnp.inf)
-        m_new = jnp.maximum(m, scf.max(-1))
-        p = jnp.exp(scf - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        l = l * corr + p.sum(-1)
-        o = o * corr[..., None] + jnp.einsum(
-            "bhqk,bhkd->bhqd", p.astype(vb.dtype), vb,
-            preferred_element_type=jnp.float32)
-        return (m_new, l, o), None
+        q_pos = i * chunk + jnp.arange(chunk)
+        mask = q_pos[:, None] >= jnp.arange(kb.shape[1])[None, :]
+        sc = jnp.where(mask[None, None], sc.astype(jnp.float32) * scale,
+                       -jnp.inf)
+        p = jax.nn.softmax(sc, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vb.dtype), vb,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
 
-    m0 = jnp.full((b, h, s), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((b, h, s), jnp.float32)
-    o0 = jnp.zeros((b, h, s, d), jnp.float32)
-    (m, l, o), _ = jax.lax.scan(body, (m0, l0, o0),
-                                (kc, vc, jnp.arange(nc)))
-    o = o / jnp.maximum(l, 1e-37)[..., None]
-    return jnp.swapaxes(o, 1, 2).astype(q.dtype)   # (B, S, H, D)
+    end = lambda i: (i + 1) * chunk  # noqa: E731
+    return jnp.concatenate(
+        [block(q[:, i * chunk:end(i)], k[:, :end(i)], v[:, :end(i)], i)
+         for i in range(nc)], axis=1)
 
 
 def full_causal_attention(q, k, v):
-    """Reference O(S^2)-memory attention (tests / tiny shapes)."""
+    """Reference O(S^2)-memory attention (tests / tiny shapes); v may have
+    a head size of its own."""
     b, s, h, d = q.shape
     sc = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                     preferred_element_type=jnp.float32) * d ** -0.5
@@ -252,27 +239,79 @@ def attention_block(p, x, cfg: ArchConfig, *, rope=None, positions=None,
         pr = jax.nn.softmax(sc, axis=-1).astype(cd)
         o = jnp.einsum("bhqk,bkhd->bqhd", pr, vq)
     else:                                 # train / prefill, causal
-        kq = _gqa_expand(k, cfg.n_heads)
-        vq = _gqa_expand(v, cfg.n_heads)
-        if attn_impl == "pallas":
-            from repro.kernels import ops as kops
-            o = kops.flash_attention(q, kq, vq, causal=True)
-        elif attn_impl == "pallas-interpret":
-            from repro.kernels import ops as kops
-            o = kops.flash_attention(q, kq, vq, causal=True, interpret=True)
-        elif attn_impl == "xla-bf16-logits" and s > 1024:
-            # §Perf A8: materialize per-chunk score blocks in bf16 (the
-            # online-softmax running stats stay fp32); on TPU the Pallas
-            # kernel keeps scores in VMEM entirely — this is the XLA-path
-            # approximation of that traffic saving
-            o = chunked_causal_attention(q, kq, vq,
-                                         logit_dtype=jnp.bfloat16)
-        elif s <= 1024:
-            o = full_causal_attention(q, kq, vq)
-        else:
-            o = chunked_causal_attention(q, kq, vq)
+        o = causal_attention(q, _gqa_expand(k, cfg.n_heads),
+                             _gqa_expand(v, cfg.n_heads), attn_impl)
     out = o.reshape(b, s, cfg.n_heads * hd) @ p["wo"].astype(cd)
     return out, new_rows
+
+
+def causal_attention(q, k, v, attn_impl="xla"):
+    """Causal self-attention over a whole sequence; q, k, v (B, S, H, D),
+    v's head size may differ from q's and k's (the XLA paths only)."""
+    s = q.shape[1]
+    if attn_impl == "pallas":
+        from repro.kernels import ops as kops
+        return kops.flash_attention(q, k, v, causal=True)
+    if attn_impl == "pallas-interpret":
+        from repro.kernels import ops as kops
+        return kops.flash_attention(q, k, v, causal=True, interpret=True)
+    if attn_impl == "xla-bf16-logits" and s > 1024:
+        # §Perf A8: materialize per-chunk score blocks in bf16 (the
+        # online-softmax running stats stay fp32); on TPU the Pallas
+        # kernel keeps scores in VMEM entirely — this is the XLA-path
+        # approximation of that traffic saving
+        return chunked_causal_attention(q, k, v, logit_dtype=jnp.bfloat16)
+    if s <= 1024:
+        return full_causal_attention(q, k, v)
+    return chunked_causal_attention(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2 arXiv:2405.04434 §2.1; no q latent)
+# ---------------------------------------------------------------------------
+
+def init_mla(cfg: ArchConfig, key):
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {
+        "wq": _dense_init(k1, (d, h * qk)),
+        # the latent c (r) and the one rope key head shared by all heads
+        "wkv_a": _dense_init(k2, (d, r + cfg.qk_rope_head_dim)),
+        "kv_norm": jnp.ones((r,), jnp.float32),
+        "wkv_b": _dense_init(k3, (r, h * (cfg.qk_nope_head_dim
+                                          + cfg.v_head_dim))),
+        "wo": _dense_init(k4, (h * cfg.v_head_dim, d),
+                          fan_in=h * cfg.v_head_dim),
+    }
+
+
+def mla_block(p, x, cfg: ArchConfig, *, rope, attn_impl="xla"):
+    """q = x W_q, split per head into q_nope and q_pe; [c, k_pe] = x W_kva;
+    [k_nope, v] = RMSNorm(c) W_kvb per head (eps 1e-6); RoPE on q_pe and
+    on k_pe (one head, shared by all); causal softmax over (q_nope.k_nope
+    + q_pe.k_pe) / sqrt(q/k head size); (sum p v) W_o. RoPE rotates
+    halves of the rope part (the published code rotates interleaved
+    pairs: a fixed permutation of W_q's and W_kva's rope columns)."""
+    b, s, _ = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    cd = x.dtype
+    cos, sin = rope
+    with jax.named_scope("attention"):
+        q = (x @ p["wq"].astype(cd)).reshape(b, s, h, -1)
+    with jax.named_scope("mla_latent"):
+        kva = x @ p["wkv_a"].astype(cd)
+        c = rms_head_norm(kva[..., :r], p["kv_norm"])
+        kv = (c @ p["wkv_b"].astype(cd)).reshape(b, s, h, nope + dv)
+    with jax.named_scope("attention"):
+        q = jnp.concatenate([q[..., :nope],
+                             apply_rope(q[..., nope:], cos, sin)], -1)
+        k_pe = apply_rope(kva[..., None, r:], cos, sin)        # (B,S,1,R)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+            k_pe, (b, s, h, k_pe.shape[-1]))], -1)
+        o = causal_attention(q, k, kv[..., nope:], attn_impl)
+        return o.reshape(b, s, h * dv) @ p["wo"].astype(cd)
 
 
 # ---------------------------------------------------------------------------
@@ -287,8 +326,8 @@ def init_mlp(cfg: ArchConfig, key, d_ff=None):
             "w_down": _dense_init(k3, (d_ff, cfg.d_model), fan_in=d_ff)}
 
 
-def mlp_block(p, x):
-    with jax.named_scope("mlp"):
+def mlp_block(p, x, scope="mlp"):
+    with jax.named_scope(scope):
         cd = x.dtype
         g = jax.nn.silu(x @ p["w_gate"].astype(cd))
         u = x @ p["w_up"].astype(cd)
@@ -296,171 +335,261 @@ def mlp_block(p, x):
 
 
 # ---------------------------------------------------------------------------
-# MoE (GShard-style capacity-based dense dispatch)
+# MoE: dropless routing, the experts held here as one grouped product
 # ---------------------------------------------------------------------------
 
+# Rows per tile of the grouped product; each expert held adds at most one
+# tile of padding to the rows routed to it.
+GMM_TILE_ROWS = 128
+
+
 def init_moe(cfg: ArchConfig, key):
+    """The router over all experts and the weights of the experts held
+    here; expert e is drawn from ``fold_in(key, e)``, so a share holds the
+    uncut layer's experts."""
     m = cfg.moe
     d = cfg.d_model
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    ids = m.first_held + jnp.arange(m.held)
+
+    def experts(k, shape, fan_in=None):
+        return jax.vmap(lambda e: _dense_init(jax.random.fold_in(k, e),
+                                              shape, fan_in))(ids)
+
     p = {
         "router": _dense_init(k1, (d, m.n_experts)),
-        "w_gate": _dense_init(k2, (m.n_experts, d, m.d_ff_expert)),
-        "w_up": _dense_init(k3, (m.n_experts, d, m.d_ff_expert)),
-        "w_down": _dense_init(k4, (m.n_experts, m.d_ff_expert, d),
-                              fan_in=m.d_ff_expert),
+        "w_gate": experts(k2, (d, m.d_ff_expert)),
+        "w_up": experts(k3, (d, m.d_ff_expert)),
+        "w_down": experts(k4, (m.d_ff_expert, d), fan_in=m.d_ff_expert),
     }
     if m.n_shared_experts:
         p["shared"] = init_mlp(cfg, k5, d_ff=m.n_shared_experts * m.d_ff_shared)
+    if m.bias_std is not None:
+        p["router_bias"] = m.bias_std * jax.random.normal(
+            jax.random.fold_in(k1, 1), (m.n_experts,), jnp.float32)
     return p
 
 
-def _moe_local(x, router, wg, wu, wd, cfg: ArchConfig, e0, n_local: int,
-               mesh_axes: tuple, shared_w=None):
-    """Per-device MoE core: local routing + local scatter into THIS device's
-    expert buffer + local expert GEMMs + gather-back; partial outputs are
-    psum'd over the model axis (the only EP collective: activation-sized).
+def route(xt, p, m):
+    """Scores (T, E) in float32, the experts picked (T, k) and their
+    weights (T, k): the picked scores over their sum, scaled. The
+    correction bias, where there is one, moves the pick and not the
+    weights."""
+    logits = jnp.dot(xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if m.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    pick = scores + p["router_bias"] if "router_bias" in p else scores
+    _, idx = jax.lax.top_k(pick, m.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return scores, idx, w * m.routed_scaling
 
-    x: (B_loc, S, D) local tokens; wg/wu/wd: (n_local, d, ff) local experts;
-    e0: first local expert id (traced); mesh_axes: (model_axis?, all_axes)
-    — empty tuples outside shard_map (single-device path, e0=0,
-    n_local=E).
-    """
+
+def balance_loss(scores, idx, m, n_seq: int):
+    """aux_coef * sum_i f_i P_i, f_i = E / (k T) * #{t: i picked}, P_i the
+    mean over t of i's score over the token's score sum (DeepSeek-V3 eqs.
+    17-20); per sequence and averaged when ``seq_aux``, else over all T."""
+    t, e = scores.shape
+    g = n_seq if m.seq_aux else 1
+    picked = jax.nn.one_hot(idx, e, dtype=jnp.float32).sum(1)
+    f = picked.reshape(g, t // g, e).mean(1) * (e / m.top_k)
+    share = scores / scores.sum(-1, keepdims=True)
+    prob = share.reshape(g, t // g, e).mean(1)
+    return m.aux_coef * jnp.mean(jnp.sum(f * prob, -1))
+
+
+def _gmm_tiling(_rows: int, k: int, n: int):
+    """(rows, contraction, columns) tile of the grouped products, forward
+    and backward; the columns and contraction stay whole up to 1,536."""
+    def tile(x):
+        return 512 if x % 512 == 0 else x if x <= 1536 else 128
+    return GMM_TILE_ROWS, tile(k), tile(n)
+
+
+def grouped_matmul(lhs, rhs, sizes):
+    """lhs (M, K) rows sorted by group; rhs (G, K, N); sizes (G + 1,), the
+    last group's rows not computed. Row r of group g is lhs[r] @ rhs[g];
+    rows past the G groups are undefined. The Pallas grouped product
+    (megablox ``gmm``) visits only the tiles that hold the groups' rows;
+    off the TPU it runs in the Pallas interpreter."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as mb
+
+    rhs = rhs.astype(lhs.dtype)
+
+    def call(interpret):
+        return lambda a, b, s: mb.gmm(a, b, s, a.dtype, _gmm_tiling, None,
+                                      None, False, interpret)
+    return jax.lax.platform_dependent(lhs, rhs, sizes, tpu=call(False),
+                                      default=call(True))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _dispatch(xt, order, pos, held, k: int, t: int):
+    """Row r: token ``order[r] // k``'s row of xt (rows past the T*k
+    pairs are zero). Its transpose takes the first ``held`` rows' parts
+    back by ``pos`` and sums each token's k of them: no scatter."""
+    return jnp.take(xt, order // k, axis=0, mode="fill", fill_value=0)
+
+
+def _dispatch_fwd(xt, order, pos, held, k, t):
+    return _dispatch(xt, order, pos, held, k, t), (pos, held)
+
+
+def _dispatch_bwd(k, t, res, g):
+    pos, held = res
+    pos = pos[:t * k]
+    pairs = jnp.where((pos < held)[:, None], jnp.take(g, pos, axis=0), 0)
+    return (pairs.reshape(t, k, -1).sum(1, dtype=jnp.float32).astype(g.dtype),
+            None, None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, order, pos, held):
+    """Pair p (token p // k, choice p % k): sorted row ``pos[p]`` of ys
+    where it is one of the first ``held``, else zero. Its transpose
+    gathers by ``order``: no scatter."""
+    return jnp.where((pos < held)[:, None], jnp.take(ys, pos, axis=0), 0)
+
+
+def _combine_fwd(ys, order, pos, held):
+    return _combine(ys, order, pos, held), (order, held)
+
+
+def _combine_bwd(res, g):
+    order, held = res
+    rows = jnp.take(g, order, axis=0, mode="fill", fill_value=0)
+    return (jnp.where((jnp.arange(order.shape[0]) < held)[:, None], rows, 0),
+            None, None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _tile_rows(sizes, tm: int):
+    """Rows the grouped product computes: each group's rows, out to the
+    tiles they start and end in."""
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    rows = (-(-ends // tm) - starts // tm) * tm
+    return jnp.sum(jnp.where(sizes > 0, rows, 0))
+
+
+def _moe_local(x, p, cfg: ArchConfig, e0, n: int):
+    """The routed experts ``e0 .. e0 + n - 1`` of one device, dropless.
+
+    Routes every token over all experts, sorts the (token, choice) pairs
+    routed to the experts held here by expert, computes them with one
+    grouped product per weight and adds each back with its weight. The
+    pairs routed elsewhere are left out: on a mesh the caller sums the
+    devices' parts. Returns (y, balance loss, rows per expert held, rows
+    the grouped product computes)."""
     m = cfg.moe
     b, s, d = x.shape
-    t = b * s
-    cd = x.dtype
+    t, k = b * s, m.top_k
+    rows = -(-t * k // GMM_TILE_ROWS) * GMM_TILE_ROWS
     xt = x.reshape(t, d)
-    logits = (xt @ router.astype(cd)).astype(jnp.float32)     # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, m.top_k)       # (T, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    capacity = max(int(m.capacity_factor * m.top_k * t / m.n_experts), 4)
-
-    # position of each (token, choice) within its GLOBAL expert queue —
-    # identical on every model shard (replicated routing compute)
-    onehot = (gate_idx.reshape(t * m.top_k)[:, None] ==
-              jnp.arange(m.n_experts)[None, :])               # (T*k, E)
-    pos = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1
-    pos_in_expert = jnp.where(onehot, pos, 0).max(-1)         # (T*k,)
-    keep = pos_in_expert < capacity
-    gid = gate_idx.reshape(t * m.top_k)
-
-    # local scatter: only (token, choice) pairs routed to THIS device's
-    # experts land in the buffer; everything else is OOB-dropped
-    local_ok = keep & (gid >= e0) & (gid < e0 + n_local)
-    dest = jnp.where(local_ok, (gid - e0) * capacity + pos_in_expert,
-                     n_local * capacity)
-    updates = jnp.broadcast_to(xt[:, None, :], (t, m.top_k, d)) \
-        .reshape(t * m.top_k, d)
-    buf = jnp.zeros((n_local * capacity, d), cd)
-    buf = buf.at[dest].add(updates, mode="drop")
-    bufE = buf.reshape(n_local, capacity, d)
-
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", bufE, wg.astype(cd))) \
-        * jnp.einsum("ecd,edf->ecf", bufE, wu.astype(cd))
-    ye = jnp.einsum("ecf,efd->ecd", h, wd.astype(cd))         # (E_loc,C,D)
-
-    yflat = ye.reshape(n_local * capacity, d)
-    ygath = yflat.at[dest].get(mode="fill", fill_value=0)     # (T*k, D)
-    w = (gate_vals.reshape(t * m.top_k)
-         * local_ok.astype(jnp.float32)).astype(cd)
-    y = (ygath * w[:, None]).reshape(t, m.top_k, d).sum(1)
-
-    model_axis, all_axes = mesh_axes
-    if shared_w is not None:
-        # fused shared expert: this device's ff slice contributes a partial
-        # sum that rides the EP psum below (one collective, not two)
-        sg, su, sd_ = shared_w
-        hs = jax.nn.silu(xt @ sg.astype(cd)) * (xt @ su.astype(cd))
-        y = y + hs @ sd_.astype(cd)
-    if model_axis is not None:
-        y = jax.lax.psum(y, model_axis)                       # EP combine
-
-    # load-balance aux loss (Switch style), replicated across the mesh
-    me = probs.mean(0)
-    ce = onehot.reshape(t, m.top_k, m.n_experts).astype(
-        jnp.float32).sum(1).mean(0) * m.top_k
-    aux = m.router_aux_coef * m.n_experts * jnp.sum(me * ce)
-    if all_axes:
-        aux = jax.lax.pmean(aux, all_axes)
-    return y.reshape(b, s, d), aux
+    with jax.named_scope("moe_route"):
+        scores, idx, w = route(xt, p, m)
+        aux = balance_loss(scores, idx, m, b)
+        gid = idx.reshape(t * k)
+        here = (gid >= e0) & (gid < e0 + n)
+        lid = jnp.pad(jnp.where(here, gid - e0, n), (0, rows - t * k),
+                      constant_values=n)
+        sizes = jnp.bincount(lid, length=n + 1).astype(jnp.int32)
+        held = jnp.sum(sizes[:n])
+    with jax.named_scope("moe_dispatch"):
+        # the pairs held here first, by expert; rows past ``held`` are
+        # never read back, and take no gradient
+        order = jnp.argsort(lid, stable=True).astype(jnp.int32)
+        pos = jnp.zeros_like(order).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32))
+        xs = _dispatch(xt, order, pos, held, k, t)
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(grouped_matmul(xs, p["w_gate"], sizes)) \
+            * grouped_matmul(xs, p["w_up"], sizes)
+        ys = grouped_matmul(h, p["w_down"], sizes)
+    with jax.named_scope("moe_combine"):
+        yp = _combine(ys, order, pos[:t * k], held).reshape(t, k, d)
+        y = jnp.einsum("tkd,tk->td", yp, w.astype(x.dtype),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    return (y.reshape(b, s, d), aux, sizes[:n],
+            _tile_rows(sizes[:n], GMM_TILE_ROWS))
 
 
-def moe_block(p, x, cfg: ArchConfig, *, capacity: Optional[int] = None):
-    """Top-k capacity MoE. Returns (y, aux_loss).
+def moe_block(p, x, cfg: ArchConfig):
+    """Dropless MoE over the experts held here, plus the shared experts.
+    Returns (y, balance loss, counters).
 
-    On a mesh: expert-parallel shard_map — experts shard over the model
-    axis, tokens stay on their data shard, dispatch scatter/gather is
-    device-local, and the only collective is an activation-sized psum.
-    (The GShard dense-dispatch einsum costs O(T*E*C*D) MXU FLOPs —
-    measured 200x the expert GEMMs on olmoe — and GSPMD cannot partition a
-    scatter indexed on the sharded expert dim without replicating the
-    buffers; the explicit shard_map path avoids both. See DESIGN.md §5.)
-    """
+    On a mesh the layer runs per device in a shard_map (its grouped
+    products are Pallas kernels, which the partitioner cannot split):
+    tokens stay on their data shard where the batch divides the batch
+    axes, and the held experts shard over "model" where they divide it
+    (expert parallelism). Every device routes its tokens over all experts
+    and computes its own experts' pairs (``_moe_local``); the only
+    collective is the activation-sized psum of the partial outputs."""
     from repro.sharding.rules import current_rules
 
     m = cfg.moe
     rules = current_rules()
     mesh = rules.mesh if rules else None
-    use_shard_map = False
-    if mesh is not None and "model" in mesh.axis_names:
-        model_size = int(mesh.shape["model"])
-        batch_axes = rules.table.get("batch", ())
-        bsz = 1
-        for a in batch_axes:
-            bsz *= int(mesh.shape[a])
-        use_shard_map = (m.n_experts % model_size == 0
-                         and x.shape[0] % bsz == 0 and model_size > 1)
+    router = {k: p[k] for k in ("router", "router_bias") if k in p}
+    experts = (p["w_gate"], p["w_up"], p["w_down"])
+    if mesh is None or mesh.size == 1:
+        y, aux, sizes, gemm = _moe_local(
+            x, dict(router, w_gate=experts[0], w_up=experts[1],
+                    w_down=experts[2]), cfg, m.first_held, m.held)
+        held, most = jnp.sum(sizes), jnp.max(sizes)
+    else:
+        y, aux, held, gemm, most = _moe_sharded(
+            x, router, experts, cfg, mesh, rules.table.get("batch", ()))
+    stats = {"moe/held_rows": held, "moe/gemm_rows": gemm,
+             "moe/max_expert_rows": most}
+    if m.n_shared_experts:
+        y = y + mlp_block(p["shared"], x, scope="shared_expert")
+    return y, aux, stats
 
-    if not use_shard_map:
-        y, aux = _moe_local(x, p["router"], p["w_gate"], p["w_up"],
-                            p["w_down"], cfg, 0, m.n_experts, (None, ()))
-        if m.n_shared_experts:
-            y = y + mlp_block(p["shared"], x)
-        return y, aux
 
-    n_local = m.n_experts // model_size
-    b_ax = batch_axes if len(batch_axes) > 1 else batch_axes[0]
-    fuse = bool(m.n_shared_experts and m.fuse_shared)
-
-    if fuse:
-        def body(xl, router, wg, wu, wd, sg, su, sd_):
-            e0 = jax.lax.axis_index("model") * n_local
-            return _moe_local(xl, router, wg, wu, wd, cfg, e0, n_local,
-                              ("model", mesh.axis_names),
-                              shared_w=(sg, su, sd_))
-
-        y, aux = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(b_ax, None, None), P(None, None),
-                      P("model", None, None), P("model", None, None),
-                      P("model", None, None),
-                      P(None, "model"), P(None, "model"),
-                      P("model", None)),
-            out_specs=(P(b_ax, None, None), P()),
-            check_vma=False,
-        )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-          p["shared"]["w_gate"], p["shared"]["w_up"],
-          p["shared"]["w_down"])
-        return y, aux
+def _moe_sharded(x, router, experts, cfg: ArchConfig, mesh, batch_axes):
+    m = cfg.moe
+    bsz = 1
+    for a in batch_axes:
+        bsz *= int(mesh.shape[a])
+    data = tuple(batch_axes) if batch_axes and x.shape[0] % bsz == 0 else ()
+    n_model = int(mesh.shape["model"]) if "model" in mesh.axis_names else 1
+    ep = "model" if n_model > 1 and m.held % n_model == 0 else None
+    n_local = m.held // n_model if ep else m.held
+    split = data + ((ep,) if ep else ())     # the axes that split the work
 
     def body(xl, router, wg, wu, wd):
-        e0 = jax.lax.axis_index("model") * n_local
-        return _moe_local(xl, router, wg, wu, wd, cfg, e0, n_local,
-                          ("model", mesh.axis_names))
+        e0 = m.first_held
+        if ep:
+            e0 = e0 + jax.lax.axis_index(ep) * n_local
+        y, aux, sizes, gemm = _moe_local(
+            xl, dict(router, w_gate=wg, w_up=wu, w_down=wd), cfg, e0,
+            n_local)
+        held = jnp.sum(sizes)
+        if data:                # an expert's rows come from every data shard
+            sizes = jax.lax.psum(sizes, data)
+        most = jnp.max(sizes)
+        if ep:
+            y = jax.lax.psum(y, ep)
+            most = jax.lax.pmax(most, ep)
+        if split:
+            held, gemm = jax.lax.psum(held, split), jax.lax.psum(gemm, split)
+        return y, jax.lax.pmean(aux, mesh.axis_names), held, gemm, most
 
-    y, aux = jax.shard_map(
+    tokens = P(data if len(data) > 1 else (data[0] if data else None),
+               None, None)
+    held_w = P(ep, None, None)
+    return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(b_ax, None, None), P(None, None),
-                  P("model", None, None), P("model", None, None),
-                  P("model", None, None)),
-        out_specs=(P(b_ax, None, None), P()),
+        in_specs=(tokens, P(), held_w, held_w, held_w),
+        out_specs=(tokens, P(), P(), P(), P()),
         check_vma=False,
-    )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
-
-    if m.n_shared_experts:
-        y = y + mlp_block(p["shared"], x)
-    return y, aux
+    )(x, router, *experts)

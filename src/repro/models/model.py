@@ -38,8 +38,7 @@ def make_ctx(cfg: ArchConfig, seq_len: int, mode: str, *,
     ctx = {"mode": mode, "attn_impl": attn_impl, "remat": remat,
            "compute_dtype": compute_dtype}
     if not cfg.attention_free:
-        hd = cfg.resolved_head_dim
-        ctx["rope"] = B.rope_table(seq_len, hd, cfg.rope_theta)
+        ctx["rope"] = B.rope_table(seq_len, cfg.rope_dim, cfg.rope_theta)
     if vision is not None:
         ctx["vision"] = vision
     if cache_len is not None:
@@ -95,8 +94,8 @@ def loss_fn(params, batch, cfg: ArchConfig, ctx: dict):
     nll = (logz - gold) * valid
     ntok = jnp.maximum(valid.sum(), 1)
     loss = nll.sum() / ntok
-    metrics = {"loss": loss, "aux_loss": aux, "ntokens": ntok}
-    return loss + aux, metrics
+    metrics = dict(aux, loss=loss, ntokens=ntok)
+    return loss + aux["aux_loss"], metrics
 
 
 def prefill(params, tokens, cfg: ArchConfig, ctx: dict):

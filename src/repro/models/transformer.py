@@ -2,7 +2,9 @@
 
 Layouts (keeps HLO size ~one layer body regardless of depth):
   uniform : one ``lax.scan`` over all (stacked-param) layers
-            -> dense, moe, rwkv archs
+            -> dense, moe, rwkv archs; an MoE model's leading dense
+            layers (``first_k_dense``) are a scanned stack of their own
+            that runs first
   periodic: outer scan over periods of [inner scan of k homogeneous layers +
             one special layer], + trailing inner layers
             -> vlm   (4 dense + 1 cross-attn) x 8
@@ -48,7 +50,10 @@ def build_layout(cfg: ArchConfig) -> dict:
     block = {"ssm": "rwkv"}.get(cfg.family)
     if block is None:
         block = "moe" if cfg.moe is not None else "dense"
-    return {"kind": "uniform", "block": block, "n": cfg.n_layers}
+    # leading dense layers of an MoE model: a stack of their own, first
+    lead = cfg.first_k_dense if block == "moe" else 0
+    return {"kind": "uniform", "block": block, "n": cfg.n_layers - lead,
+            "lead": lead}
 
 
 # ---------------------------------------------------------------------------
@@ -57,12 +62,13 @@ def build_layout(cfg: ArchConfig) -> dict:
 
 def init_layer(block: str, cfg: ArchConfig, key):
     k1, k2, k3 = jax.random.split(key, 3)
+    init_attn = B.init_mla if cfg.mla else B.init_attention
     if block == "dense" or block == "shared_attn":
-        return {"attn": B.init_attention(cfg, k1),
+        return {"attn": init_attn(cfg, k1),
                 "mlp": B.init_mlp(cfg, k2),
                 "ln1": B.init_norm(cfg), "ln2": B.init_norm(cfg)}
     if block == "moe":
-        return {"attn": B.init_attention(cfg, k1),
+        return {"attn": init_attn(cfg, k1),
                 "moe": B.init_moe(cfg, k2),
                 "ln1": B.init_norm(cfg), "ln2": B.init_norm(cfg)}
     if block == "cross_attn":
@@ -83,26 +89,49 @@ def init_layer(block: str, cfg: ArchConfig, key):
 ATTN_BLOCKS = ("dense", "moe", "shared_attn")
 
 
+def zero_aux(cfg: ArchConfig) -> dict:
+    """What a layer adds to the step's metrics: the balance loss and, in
+    an MoE model, the routing counters (``moe/...``)."""
+    aux = {"aux_loss": jnp.zeros((), jnp.float32)}
+    if cfg.moe is not None:
+        aux.update({k: jnp.zeros((), jnp.int32) for k in (
+            "moe/held_rows", "moe/gemm_rows", "moe/max_expert_rows")})
+    return aux
+
+
+def add_aux(a: dict, b: dict) -> dict:
+    """Sums over layers; the largest expert load is the most of any."""
+    return {k: jnp.maximum(a[k], b[k]) if k == "moe/max_expert_rows"
+            else a[k] + b[k] for k in a}
+
+
 def layer_fwd(block: str, p, x, cfg: ArchConfig, ctx: dict,
               state=None, collect_kv: bool = False):
-    """Returns (x, new_state, aux, kv_out). In decode, an attention block's
-    new_state is its new (k, v) rows, a cross-attention block's None."""
-    aux = jnp.zeros((), jnp.float32)
+    """Returns (x, new_state, aux, kv_out), aux as ``zero_aux``. In
+    decode, an attention block's new_state is its new (k, v) rows, a
+    cross-attention block's None."""
+    aux = zero_aux(cfg)
     kv_out = None
     decode = ctx["mode"] == "decode"
     if block in ATTN_BLOCKS:
         h = B.apply_norm(p["ln1"], x, cfg)
         kv_cache = state if decode else None
-        with jax.named_scope("attention"):
-            o, new_rows = B.attention_block(
-                p["attn"], h, cfg, rope=ctx.get("rope"),
-                positions=ctx.get("positions"),
-                kv_cache=kv_cache, cache_len=ctx.get("cache_len"),
-                attn_impl=ctx.get("attn_impl", "xla"))
+        new_rows = None
+        if cfg.mla:
+            o = B.mla_block(p["attn"], h, cfg, rope=ctx["rope"],
+                            attn_impl=ctx.get("attn_impl", "xla"))
+        else:
+            with jax.named_scope("attention"):
+                o, new_rows = B.attention_block(
+                    p["attn"], h, cfg, rope=ctx.get("rope"),
+                    positions=ctx.get("positions"),
+                    kv_cache=kv_cache, cache_len=ctx.get("cache_len"),
+                    attn_impl=ctx.get("attn_impl", "xla"))
         x = x + o
         h = B.apply_norm(p["ln2"], x, cfg)
         if block == "moe":
-            y, aux = B.moe_block(p["moe"], h, cfg)
+            y, loss, stats = B.moe_block(p["moe"], h, cfg)
+            aux = dict(stats, aux_loss=loss)
         else:
             y = B.mlp_block(p["mlp"], h)
         x = x + y
@@ -172,7 +201,11 @@ def _stack_init(block: str, cfg: ArchConfig, key, n: int):
 def init_stack(cfg: ArchConfig, key):
     layout = build_layout(cfg)
     if layout["kind"] == "uniform":
-        return {"layers": _stack_init(layout["block"], cfg, key, layout["n"])}
+        out = {"layers": _stack_init(layout["block"], cfg, key, layout["n"])}
+        if layout["lead"]:
+            out["lead_layers"] = _stack_init(
+                "dense", cfg, jax.random.fold_in(key, 1), layout["lead"])
+        return out
     k1, k2, k3, k4 = jax.random.split(key, 4)
     periods, inner_n = layout["periods"], layout["inner_n"]
     inner = jax.vmap(lambda k: _stack_init(layout["inner_block"], cfg, k,
@@ -214,18 +247,17 @@ def _scan_layers(block: str, stacked, x, cfg, ctx, states=None,
             x, aux = carry
             p, st = xs
             x, out, a, _ = layer_fwd(block, p, x, cfg, ctx, st)
-            return (x, aux + a), out
+            return (x, add_aux(aux, a)), out
         (x, aux), outs = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), (stacked, states))
+            body, (x, zero_aux(cfg)), (stacked, states))
         return x, aux, outs, None
 
     def body(carry, p):
         x, aux = carry
         x, _, a, kv = layer_fwd(block, p, x, cfg, ctx, None, collect_kv)
-        return (x, aux + a), kv
+        return (x, add_aux(aux, a)), kv
     body = _maybe_remat(body, ctx)
-    (x, aux), kvs = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                 stacked)
+    (x, aux), kvs = jax.lax.scan(body, (x, zero_aux(cfg)), stacked)
     return x, aux, None, kvs
 
 
@@ -263,9 +295,14 @@ def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
     layout = build_layout(cfg)
     cache_len = ctx.get("cache_len")
     if layout["kind"] == "uniform":
+        lead = zero_aux(cfg)
+        if layout["lead"]:
+            x, lead, _, _ = _scan_layers("dense", params["lead_layers"], x,
+                                         cfg, ctx)
         x, aux, outs, _ = _scan_layers(
             layout["block"], params["layers"], x, cfg, ctx,
             None if states is None else states["layers"])
+        aux = add_aux(lead, aux)
         if states is None:
             return x, aux, None
         return x, aux, {"layers": _commit(layout["block"], states["layers"],
@@ -276,7 +313,7 @@ def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
     single_block = layout["single_block"]
     decode = ctx["mode"] == "decode"
     shared_p = params.get("shared_block")
-    aux0 = jnp.zeros((), jnp.float32)
+    aux0 = zero_aux(cfg)
 
     if decode:
         def outer(carry, xs):
@@ -290,7 +327,8 @@ def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
                 inner_block, inner_p, x, cfg, ctx, inner_st)
             x, single_out, a2, _ = layer_fwd(
                 single_block, single_p, x, cfg, ctx, single_st)
-            return (x, aux + a1 + a2), (inner_out, single_out)
+            return (x, add_aux(add_aux(aux, a1), a2)), (inner_out,
+                                                        single_out)
 
         if single_block == "cross_attn":
             xs = ((params["layers"]["inner"], params["layers"]["single"]),
@@ -309,7 +347,7 @@ def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
             x, a3, tr_out, _ = _scan_layers(
                 inner_block, params["layers"]["trailing"], x, cfg, ctx,
                 states["trailing"])
-            aux = aux + a3
+            aux = add_aux(aux, a3)
             new_states["trailing"] = _commit(inner_block, states["trailing"],
                                              tr_out, cache_len)
         return x, aux, new_states
@@ -322,7 +360,7 @@ def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
             inner_p, single_p = xs, shared_p
         x, a1, _, _ = _scan_layers(inner_block, inner_p, x, cfg, ctx)
         x, _, a2, _ = layer_fwd(single_block, single_p, x, cfg, ctx)
-        return (x, aux + a1 + a2), None
+        return (x, add_aux(add_aux(aux, a1), a2)), None
 
     if single_block == "cross_attn":
         xs = (params["layers"]["inner"], params["layers"]["single"])
@@ -332,7 +370,7 @@ def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
     if layout["trailing"]:
         x, a3, _, _ = _scan_layers(inner_block,
                                    params["layers"]["trailing"], x, cfg, ctx)
-        aux = aux + a3
+        aux = add_aux(aux, a3)
     return x, aux, None
 
 
@@ -343,6 +381,10 @@ def apply_stack(params, x, cfg: ArchConfig, ctx: dict, states=None):
 def init_decode_state(cfg: ArchConfig, batch: int, buffer_len: int,
                       dtype=jnp.bfloat16, vision=None, params=None):
     """Zeroed decode state (cache buffers) for the whole stack."""
+    if cfg.mla:
+        raise ValueError(
+            f"{cfg.name}: decoding with latent attention (MLA) needs a "
+            f"latent K/V cache, which the decode path does not have yet")
     hd = cfg.resolved_head_dim
     layout = build_layout(cfg)
 

@@ -82,7 +82,10 @@ def constrain(x, logical: Sequence[Optional[str]]):
 # ---------------------------------------------------------------------------
 
 _COL_TP = {"wq", "wk", "wv", "wg", "wr", "w_up", "w_gate", "cm_wk",
-           "cm_wr", "z_proj", "x_proj", "conv_x", "lm_head"}
+           "cm_wr", "z_proj", "x_proj", "conv_x", "lm_head", "wkv_b"}
+# replicated over tp: MLA's down projection "wkv_a" (the latent and the
+# one shared rope key head) and its latent norm, the router and its
+# correction bias
 _ROW_TP = {"wo", "out_proj", "cm_wv", "w_down"}
 _VEC_TP = {"conv_b_x", "gate_norm", "ln_x"}
 

@@ -111,6 +111,11 @@ class SupervisorReport:
     # seconds between successive step completions, one per step run
     step_s: list = dataclasses.field(default_factory=list)
     save_s: list = dataclasses.field(default_factory=list)   # per save
+    # the step's counters (its metrics named "moe/..."), one per step run
+    counters: dict = dataclasses.field(default_factory=dict)
+
+    def counter_means(self) -> dict:
+        return {k: statistics.mean(v) for k, v in self.counters.items()}
 
 
 class TrainSupervisor:
@@ -151,6 +156,9 @@ class TrainSupervisor:
                     statistics.median(report.step_s):
                 report.straggler_steps.append(i)
             report.step_s.append(dt)
+            for k, v in metrics.items():
+                if k.startswith("moe/"):
+                    report.counters.setdefault(k, []).append(float(v))
 
         while step < n_steps:
             try:

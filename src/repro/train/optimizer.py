@@ -50,6 +50,18 @@ def init_opt_state(params, master_weights: bool = False):
     return state
 
 
+# Leaves AdamW does not update: an MoE router's correction bias picks
+# experts and takes no gradient (DeepSeek-V3 noaux_tc; the rule that moves
+# it in published training lies outside the optimizer).
+FROZEN = ("router_bias",)
+
+
+def trainable(params):
+    """A tree of bools shaped like ``params``: False for FROZEN leaves."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) not in FROZEN, params)
+
+
 def global_norm(tree) -> jnp.ndarray:
     leaves = [jnp.sum(jnp.square(g.astype(jnp.float32)))
               for g in jax.tree.leaves(tree)]
@@ -70,7 +82,9 @@ def adamw_update(cfg: OptimizerConfig, params, grads, opt_state):
     masters = opt_state.get("master")
     base = masters if masters is not None else params
 
-    def upd(p, out_dtype, g, mu, nu):
+    def upd(p, out_dtype, train, g, mu, nu):
+        if not train:
+            return p.astype(out_dtype), p.astype(jnp.float32), mu, nu
         g = g.astype(jnp.float32) * scale
         mu = b1 * mu + (1 - b1) * g
         nu = b2 * nu + (1 - b2) * jnp.square(g)
@@ -83,8 +97,8 @@ def adamw_update(cfg: OptimizerConfig, params, grads, opt_state):
         return new32.astype(out_dtype), new32, mu, nu
 
     dtypes = jax.tree.map(lambda p: p.dtype, params)
-    out = jax.tree.map(upd, base, dtypes, grads, opt_state["mu"],
-                       opt_state["nu"])
+    out = jax.tree.map(upd, base, dtypes, trainable(params), grads,
+                       opt_state["mu"], opt_state["nu"])
     pick = lambda i: jax.tree.map(lambda t: t[i], out,
                                   is_leaf=lambda t: isinstance(t, tuple))
     new_params = pick(0)

@@ -5,7 +5,8 @@ Nothing runs: the TPU compiler refuses here what the chip would refuse
 Pallas kernels at real widths, the olmo-1b serve step at full width and
 depth, the generation cell's serve step writing its donated cache in
 place, the olmo-1b decode step with its cache sharded over the 2x2 mesh,
-and the olmo-1b train step sharded over the 2x2 mesh.
+the olmo-1b train step sharded over the 2x2 mesh, the MoE cell's train
+step on one chip, and its expert-parallel step over the 2x2 mesh.
 
 The topology is described inside a module fixture, never at import, so
 only the worker that runs this file loads the TPU compiler.
@@ -253,3 +254,96 @@ def test_sharded_olmo_train_step_compiles_on_2x2(topo):
     # half of the state (the tied embedding shards over "model" only)
     assert mem.argument_size_in_bytes < state_bytes / 2
     assert "all-gather" in compiled.as_text()
+
+
+def _moonlight(n_layers):
+    """moonlight-16b-a3b at the MoE cell's share: 8 of 64 experts held,
+    a vocabulary of 20,480, every width as published."""
+    base = get_arch("moonlight-16b-a3b")
+    return dataclasses.replace(base, n_layers=n_layers, vocab_size=20480,
+                               moe=dataclasses.replace(base.moe, n_held=8))
+
+
+def test_moe_cell_train_step_fits_one_chip(one_chip):
+    """The MoE training cell's step (1 dense + 4 expert layers, B=2 x
+    S=4096, parameters and AdamW state float32) compiles for one v5e with
+    the grouped expert products as Mosaic kernels (forward, recomputed
+    forward, and the backward's two products, for gate, up and down),
+    and its arguments and temporaries fit the chip."""
+    from repro.models import model as M
+    from repro.train.optimizer import OptimizerConfig
+    from repro.train.train_step import (TrainConfig, make_opt_state,
+                                        make_train_step)
+
+    cfg = _moonlight(5)
+    tcfg = TrainConfig(remat="full")
+    step = make_train_step(cfg, tcfg, OptimizerConfig(
+        lr=3e-3, warmup_steps=5, total_steps=100, weight_decay=0.0))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(functools.partial(M.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    opt = jax.eval_shape(functools.partial(make_opt_state, tcfg=tcfg),
+                         params)
+    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt),
+        {"tokens": tokens, "labels": tokens}).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 12
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_expert_parallel_moe_step_compiles_on_2x2(topo):
+    """moonlight's dense layer and one expert layer, trained over the 2x2
+    mesh (FSDP over "data", the 8 held experts over "model"): routing,
+    dispatch, the grouped products and the combine stay on each device
+    (no collective under their scopes, no all-to-all), no device gathers
+    the other's experts, and the experts' parts meet in the
+    activation-sized psum."""
+    import re
+
+    from repro.launch.train import build_sharded_train
+    from repro.models import model as M
+    from repro.sharding import rules as SR
+    from repro.train.optimizer import OptimizerConfig
+    from repro.train.train_step import TrainConfig, make_opt_state
+
+    cfg = _moonlight(2)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    tcfg = TrainConfig()
+    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=5, total_steps=5)
+    batch, seq = 8, 1024
+    try:
+        step, _, _ = build_sharded_train(cfg, tcfg, ocfg, mesh)
+        params = jax.eval_shape(functools.partial(M.init_params, cfg),
+                                jax.random.PRNGKey(0))
+        opt = jax.eval_shape(functools.partial(make_opt_state, tcfg=tcfg),
+                             params)
+        tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+        compiled = step.lower(params, opt, {"tokens": tokens,
+                                            "labels": tokens}).compile()
+    finally:
+        SR.set_rules(None)
+    collective = re.compile(r"= (.*?) (all-gather|all-reduce|all-to-all|"
+                            r"collective-permute|reduce-scatter)(-start)?\(")
+    d, ff = cfg.d_model, cfg.moe.d_ff_expert
+    activation = f"[{batch // 2},{seq},{d}]"
+    reduced = []
+    for line in compiled.as_text().splitlines():
+        m = collective.search(line)
+        if not m:
+            continue
+        assert m.group(2) != "all-to-all", line
+        op = re.search(r'op_name="([^"]*)"', line)
+        assert not (op and re.search(r"moe_(route|dispatch|experts|combine)",
+                                     op.group(1))), line
+        assert f"[8,{d},{ff}]" not in m.group(1), line
+        assert f"[8,{ff},{d}]" not in m.group(1), line
+        if m.group(2) == "all-reduce":
+            reduced.append(m.group(1))
+    assert any(activation in shape for shape in reduced), reduced
