@@ -17,18 +17,54 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import itertools
 import json
 import os
 import threading
 import time
+import uuid
 from pathlib import Path
+from queue import Queue
 from typing import Iterable, Optional
 
 from repro.core.trace import span
 
 
+# A blob is written and hashed in pieces of this size; a payload smaller
+# than one piece is hashed inline, a larger one on a thread of its own.
+PIECE_BYTES = 32 << 20
+
+
 class DataLakeError(RuntimeError):
     pass
+
+
+@dataclasses.dataclass(frozen=True)
+class BlobWrite:
+    blob: str            # sha256 of the blob's bytes: its name
+    size: int
+    hash_wait_s: float   # from the last write to the digest being ready
+
+
+def _pieces(chunks: Iterable):
+    """Byte views of at most ``PIECE_BYTES`` over each buffer, in order."""
+    for chunk in chunks:
+        view = memoryview(chunk).cast("B")
+        for i in range(0, view.nbytes, PIECE_BYTES):
+            yield view[i:i + PIECE_BYTES]
+
+
+def _hash_from(h, queue: Queue, failed: list) -> None:
+    """The hashing thread: update ``h`` with each queued piece up to a
+    ``None``. A failure is kept for the writer, and the queue is still
+    drained so the writer never blocks on it."""
+    with span("lake/hash"):
+        for piece in iter(queue.get, None):
+            if not failed:
+                try:
+                    h.update(piece)
+                except Exception as e:
+                    failed.append(e)
 
 
 @dataclasses.dataclass
@@ -82,16 +118,65 @@ class Storage:
         os.replace(tmp, self._catalog_path)
 
     # -- blobs ("S3") --------------------------------------------------
-    def _put_blob(self, data: bytes) -> str:
-        with span("lake/hash"):
-            h = hashlib.sha256(data).hexdigest()
-        p = self.blob_dir / h
-        if not p.exists():
-            with span("lake/write"):
-                tmp = p.with_suffix(".tmp-%d" % os.getpid())
-                tmp.write_bytes(data)
+    def _put_blob(self, chunks: Iterable) -> BlobWrite:
+        """Write the concatenated ``chunks`` (buffers) as one content-
+        addressed blob, in one pass and with no copy of the payload.
+
+        Each buffer is cut into pieces of ``PIECE_BYTES``. A payload
+        smaller than one piece is hashed inline; a larger one is hashed
+        on a thread that takes each piece through a short queue while
+        this thread writes it, so the put takes the longer of the two
+        and not their sum (sha256 and file writes release the GIL). The
+        file is written under a temporary name and renamed to its sha256
+        only when complete; if anything raises, it is removed."""
+        pieces = _pieces(chunks)
+        head, ahead = [], 0
+        for piece in pieces:            # look ahead up to one piece
+            head.append(piece)
+            ahead += piece.nbytes
+            if ahead >= PIECE_BYTES:
+                break
+        h = hashlib.sha256()
+        queue = None
+        if ahead < PIECE_BYTES:         # the whole payload is in hand
+            with span("lake/hash"):
+                for piece in head:
+                    h.update(piece)
+        else:
+            queue, failed = Queue(maxsize=2), []
+            hasher = threading.Thread(target=_hash_from, name="lake-hash",
+                                      args=(h, queue, failed), daemon=True)
+            hasher.start()
+        tmp = self.blob_dir / f"{uuid.uuid4().hex}.tmp-{os.getpid()}"
+        try:
+            size = 0
+            with span("lake/write"), open(tmp, "wb") as f:
+                for piece in itertools.chain(head, pieces):
+                    if queue is not None:
+                        queue.put(piece)
+                    f.write(piece)
+                    size += piece.nbytes
+            written = time.perf_counter()
+            if queue is not None:
+                queue.put(None)
+                queue = None
+                hasher.join()
+                if failed:
+                    raise failed[0]
+            blob = h.hexdigest()
+            hash_wait_s = time.perf_counter() - written
+            p = self.blob_dir / blob
+            if p.exists():              # the same bytes are stored already
+                tmp.unlink()
+            else:
                 os.replace(tmp, p)
-        return h
+        except BaseException:
+            if queue is not None:       # let the hasher drain and stop
+                queue.put(None)
+                hasher.join()
+            tmp.unlink(missing_ok=True)
+            raise
+        return BlobWrite(blob, size, hash_wait_s)
 
     def _get_blob(self, blob: str) -> bytes:
         p = self.blob_dir / blob
@@ -147,17 +232,25 @@ class Storage:
             self._save()
             return sid
 
-    def session_put(self, sid: str, path: str, data: bytes) -> None:
+    def session_put(self, sid: str, path: str, data: bytes) -> BlobWrite:
+        return self.session_put_stream(sid, path, (data,))
+
+    def session_put_stream(self, sid: str, path: str,
+                           chunks: Iterable) -> BlobWrite:
+        """Upload one declared file of the session from a sequence of
+        buffers (bytes, memoryviews, contiguous uint8 arrays), written
+        and hashed in one pass (``_put_blob``)."""
         # distinct destination per file: content-addressing guarantees
         # asynchronous uploads never overwrite each other's blobs — but the
         # catalog save must still be serialized across concurrent agents
-        blob = self._put_blob(data)
+        put = self._put_blob(chunks)
         with self._lock:
             sess = self._session(sid, "pending")
             if path not in sess["files"]:
                 raise DataLakeError(f"{path} not declared in session {sid}")
-            sess["files"][path] = [blob, len(data)]
+            sess["files"][path] = [put.blob, put.size]
             self._save()
+        return put
 
     def commit_session(self, sid: str) -> list[FileVersion]:
         """Allocate sequential version numbers; only fully-uploaded sessions

@@ -5,11 +5,20 @@ transactional upload session (a crashed save never becomes a visible
 version) with provenance edges from the training job. Restore reshards onto
 ANY mesh: arrays are saved unsharded-logical (global shape) and re-placed
 with the target mesh's NamedShardings — elastic scaling across restarts.
+
+A checkpoint is two files. ``state.bin`` holds every leaf's bytes, C order
+and in its own dtype, one after another in the state's flattening order;
+``manifest.json`` holds the step, the extra fields and the ``layout``: per
+leaf its ``key``, ``dtype`` name, ``shape`` and byte ``offset`` in
+``state.bin``. The save streams the leaves' own host buffers into the data
+lake, so the host holds one copy of the state. Checkpoints written before
+this layout hold ``state.npz`` instead, and restore reads them too.
 """
 from __future__ import annotations
 
 import io
 import json
+import math
 from typing import Any, Optional
 
 import jax
@@ -44,12 +53,28 @@ def _unflatten_like(template, flat: dict[str, Any], cast: bool = False):
         jax.tree_util.tree_structure(template), leaves)
 
 
-def _np_savable(v) -> np.ndarray:
-    """npz cannot hold bf16; widen to fp32 (dtype restored from template)."""
-    arr = np.asarray(v)
-    if arr.dtype == jnp.bfloat16:
-        arr = arr.astype(np.float32)
-    return arr
+def _fetch(flat: dict[str, Any]) -> dict[str, np.ndarray]:
+    """Every leaf on the host: all device-to-host copies start at once."""
+    for leaf in flat.values():
+        if isinstance(leaf, jax.Array):
+            leaf.copy_to_host_async()
+    return {k: np.asarray(v) for k, v in flat.items()}
+
+
+def _layout(arrays: dict[str, np.ndarray]) -> list[dict]:
+    """Each leaf's key, dtype, shape and byte offset in ``state.bin``."""
+    out, offset = [], 0
+    for key, arr in arrays.items():
+        out.append({"key": key, "dtype": arr.dtype.name,
+                    "shape": list(arr.shape), "offset": offset})
+        offset += arr.nbytes
+    return out
+
+
+def _read_layout(raw: bytes, layout: list[dict]) -> dict[str, np.ndarray]:
+    return {e["key"]: np.frombuffer(
+        raw, jnp.dtype(e["dtype"]), count=math.prod(e["shape"]),
+        offset=e["offset"]).reshape(e["shape"]) for e in layout}
 
 
 class CheckpointManager:
@@ -73,29 +98,29 @@ class CheckpointManager:
                 state["opt"] = opt_state
             flat = _flatten(state)
             with span("ckpt/fetch"):     # device-to-host copy of each leaf
-                arrays = {k: _np_savable(v) for k, v in flat.items()}
+                arrays = _fetch(flat)
             with span("ckpt/encode"):
-                buf = io.BytesIO()
-                np.savez(buf, **arrays)
-                del arrays
-                payload = buf.getvalue()
-            manifest = {"step": step, "keys": sorted(flat),
-                        "extra": extra or {}}
+                manifest = {"step": step, "extra": extra or {},
+                            "layout": _layout(arrays)}
             storage = self.project.storage
-            paths = [f"/{self.fileset}/state.npz",
+            paths = [f"/{self.fileset}/state.bin",
                      f"/{self.fileset}/manifest.json"]
             with span("lake/put"):
                 sid = storage.begin_session(paths, creator="trainer")
-                storage.session_put(sid, paths[0], payload)
+                put = storage.session_put_stream(sid, paths[0], (
+                    np.ascontiguousarray(a).reshape(-1).view(np.uint8)
+                    for a in arrays.values()))
+                del arrays
                 storage.session_put(sid, paths[1],
                                     json.dumps(manifest).encode())
                 fvs = storage.commit_session(sid)
                 fsv = self.project.filesets.create(
                     self.fileset, [f"{fv.path}@{fv.version}" for fv in fvs],
                     creator="trainer")
-                self.project.metadata.register(fsv.ref, kind="checkpoint",
-                                               step=step, run=self.run,
-                                               **(extra or {}))
+                self.project.metadata.register(
+                    fsv.ref, kind="checkpoint", step=step, run=self.run,
+                    bytes=put.size, hash_wait_s=put.hash_wait_s,
+                    **(extra or {}))
             if job_id is not None:
                 src = None
                 if input_fileset:
@@ -119,16 +144,19 @@ class CheckpointManager:
         ref = self.fileset if version is None else \
             f"{self.fileset}:{version}"
         fsv = self.project.filesets.resolve(ref)
-        raw = self.project.storage._get_blob(
-            self.project.storage.resolve(
-                f"/{self.fileset}/state.npz",
-                fsv.files[f"/{self.fileset}/state.npz"]).blob)
-        man = json.loads(self.project.storage._get_blob(
-            self.project.storage.resolve(
-                f"/{self.fileset}/manifest.json",
-                fsv.files[f"/{self.fileset}/manifest.json"]).blob))
-        npz = np.load(io.BytesIO(raw))
-        flat = {k: npz[k] for k in npz.files}
+        storage = self.project.storage
+
+        def read(name: str) -> bytes:
+            path = f"/{self.fileset}/{name}"
+            return storage._get_blob(
+                storage.resolve(path, fsv.files[path]).blob)
+
+        man = json.loads(read("manifest.json"))
+        if f"/{self.fileset}/state.npz" in fsv.files:    # the old layout
+            npz = np.load(io.BytesIO(read("state.npz")))
+            flat = {k: npz[k] for k in npz.files}
+        else:
+            flat = _read_layout(read("state.bin"), man["layout"])
         state = _unflatten_like(template, flat, cast=True)
         if mesh is not None and specs is not None:
             flat_spec = _flatten(specs)

@@ -1,6 +1,10 @@
 """Data-lake behaviour: versioning, filesets, sessions, metadata, provenance."""
+import hashlib
+
+import numpy as np
 import pytest
 
+from repro.core.datalake import storage as S
 from repro.core.datalake.fileset import FileSetManager
 from repro.core.datalake.metadata import MetadataStore
 from repro.core.datalake.provenance import ProvenanceGraph
@@ -159,3 +163,116 @@ def test_provenance_dag_traversal(lake):
     assert prov.lineage_jobs("model:1") == ["job-etl", "job-train"]
     assert prov.replay_order("model:1")[0] == "raw:1"
     assert prov.is_dag()
+
+
+# -- the streamed blob write ----------------------------------------------
+PIECE = 4096      # small pieces, so a few KB take the threaded path
+
+
+def _payload(n: int, seed: int = 0) -> bytes:
+    return np.random.default_rng(seed).integers(
+        0, 256, n, dtype=np.uint8).tobytes()
+
+
+def _chunkings(data: bytes) -> dict:
+    arr = np.frombuffer(data, np.uint8)
+    return {
+        "one": [data],
+        "many uneven": [data[:5], data[5:PIECE + 7], data[PIECE + 7:]],
+        "array views": [arr[:3 * PIECE], memoryview(arr[3 * PIECE:])],
+        "float leaves": [np.frombuffer(data, np.float32).view(np.uint8)],
+    }
+
+
+def _stray_tmp(storage):
+    return list(storage.blob_dir.glob("*.tmp-*"))
+
+
+@pytest.mark.parametrize("size", [100, 10 * PIECE + 12])
+@pytest.mark.parametrize("chunking", ["one", "many uneven", "array views",
+                                      "float leaves"])
+def test_streamed_blob_is_named_by_the_sha256_of_its_bytes(
+        tmp_path, monkeypatch, size, chunking):
+    monkeypatch.setattr(S, "PIECE_BYTES", PIECE)
+    storage = Storage(tmp_path)
+    data = _payload(size - size % 4)
+    sid = storage.begin_session(["/f"])
+    put = storage.session_put_stream(sid, "/f", _chunkings(data)[chunking])
+    fv, = storage.commit_session(sid)
+    blob = storage.blob_dir / put.blob
+    assert blob.read_bytes() == data
+    assert put.blob == fv.blob == hashlib.sha256(data).hexdigest()
+    assert put.size == fv.size == len(data)
+    assert put.hash_wait_s >= 0
+    assert storage.download("/f") == data
+    assert _stray_tmp(storage) == []
+
+
+def test_streamed_blob_at_the_real_piece_size(tmp_path):
+    storage = Storage(tmp_path)
+    data = _payload(2 * S.PIECE_BYTES + 5)
+    sid = storage.begin_session(["/big"])
+    put = storage.session_put_stream(
+        sid, "/big", [data[:S.PIECE_BYTES + 1], data[S.PIECE_BYTES + 1:]])
+    storage.commit_session(sid)
+    assert put.blob == hashlib.sha256(data).hexdigest()
+    assert (storage.blob_dir / put.blob).read_bytes() == data
+
+
+@pytest.mark.parametrize("size", [100, 10 * PIECE])
+def test_identical_payloads_leave_one_blob(tmp_path, monkeypatch, size):
+    monkeypatch.setattr(S, "PIECE_BYTES", PIECE)
+    storage = Storage(tmp_path)
+    data = _payload(size)
+    storage.upload("/a", data)
+    sid = storage.begin_session(["/b"])
+    storage.session_put_stream(sid, "/b", [data[:7], data[7:]])
+    storage.commit_session(sid)
+    assert [p.name for p in storage.blob_dir.iterdir()] == \
+        [hashlib.sha256(data).hexdigest()]
+    assert storage.resolve("/a").blob == storage.resolve("/b").blob
+
+
+@pytest.mark.parametrize("fail_after", [1, 6])
+def test_a_stream_that_raises_leaves_no_blob(tmp_path, monkeypatch,
+                                             fail_after):
+    """Raising after one small chunk (before any write) or after six
+    pieces (the hashing thread running, the file half written)."""
+    monkeypatch.setattr(S, "PIECE_BYTES", PIECE)
+    storage = Storage(tmp_path)
+    data = _payload(PIECE)
+
+    def chunks():
+        for _ in range(fail_after):
+            yield data[:PIECE // 2] if fail_after == 1 else data
+        raise OSError("source lost")
+
+    sid = storage.begin_session(["/f"])
+    with pytest.raises(OSError, match="source lost"):
+        storage.session_put_stream(sid, "/f", chunks())
+    assert list(storage.blob_dir.iterdir()) == []
+    assert storage.session_state(sid) == "pending"
+    with pytest.raises(DataLakeError, match="incomplete"):
+        storage.commit_session(sid)
+    assert not storage.exists("/f")
+
+
+def test_only_a_payload_of_a_piece_or_more_starts_a_thread(tmp_path,
+                                                           monkeypatch):
+    monkeypatch.setattr(S, "PIECE_BYTES", PIECE)
+    started = []
+
+    class Thread(S.threading.Thread):
+        def start(self):
+            started.append(self.name)
+            super().start()
+
+    monkeypatch.setattr(S.threading, "Thread", Thread)
+    storage = Storage(tmp_path)
+    storage.upload("/small", _payload(PIECE - 1))
+    sid = storage.begin_session(["/parts"])
+    storage.session_put_stream(sid, "/parts", [b"x"] * (PIECE - 1))
+    storage.commit_session(sid)
+    assert started == []
+    storage.upload("/large", _payload(PIECE))
+    assert started == ["lake-hash"]

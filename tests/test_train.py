@@ -1,6 +1,9 @@
 """Training substrate: optimizer, microbatching, compression, checkpoints,
 fault-tolerant supervision, data pipeline."""
+import io
+import json
 import time
+import tracemalloc
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +13,7 @@ import pytest
 from repro.configs.base import get_arch
 from repro.core.acai import AcaiProject
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.models import model as M
 from repro.train import compression as C
 from repro.train.checkpoints import CheckpointManager
 from repro.train.fault import JobPreempted, TrainSupervisor
@@ -165,6 +169,104 @@ def test_checkpoint_roundtrip(tmp_path):
                                   np.asarray(params["w"]))
     # provenance: checkpoint registered in metadata with its step
     assert proj.metadata.get(f"run1-ckpt:2")["step"] == 9
+
+
+def _mixed_tree():
+    """float32, bfloat16, int32 and 0-d leaves, one of them empty."""
+    return {"w": jnp.arange(12, dtype=jnp.float32).reshape(3, 4) / 7,
+            "half": {"b": (jnp.arange(5, dtype=jnp.float32) / 3
+                           ).astype(jnp.bfloat16)},
+            "ids": jnp.array([[1, -2], [3, 2 ** 30]], jnp.int32),
+            "scale": jnp.array(0.125, jnp.float32),
+            "count": jnp.array(7, jnp.int32),
+            "none": jnp.zeros((0, 3), jnp.float32)}
+
+
+def _assert_same(got, want):
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape, path
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=str(path))
+
+
+def test_checkpoint_roundtrip_keeps_every_dtype(tmp_path):
+    cfg = get_arch("olmo-1b").reduced()
+    params = M.init_params(cfg, jax.random.PRNGKey(1))
+    opt = init_opt_state(params)
+    ckpt = CheckpointManager(AcaiProject("p", tmp_path), "mixed")
+    ref = ckpt.save(3, {"model": params, "mixed": _mixed_tree()}, opt)
+    template = {"params": {"model": params, "mixed": _mixed_tree()},
+                "opt": opt}
+    state, step = ckpt.restore(template)
+    assert step == 3
+    _assert_same(state, template)
+    meta = ckpt.project.metadata.get(ref)
+    storage = ckpt.project.storage
+    fsv = ckpt.project.filesets.resolve(ref)
+    blob = storage.resolve("/mixed-ckpt/state.bin",
+                           fsv.files["/mixed-ckpt/state.bin"])
+    leaves = jax.tree.leaves(template)
+    assert meta["bytes"] == blob.size == sum(np.asarray(a).nbytes
+                                             for a in leaves)
+    assert meta["hash_wait_s"] >= 0
+    # a float32 template widens the leaf saved as bfloat16
+    wide = jax.tree.map(lambda a: a.astype(jnp.float32)
+                        if a.dtype == jnp.bfloat16 else a, template)
+    state, _ = ckpt.restore(wide)
+    _assert_same(state, wide)
+
+
+def test_checkpoint_restores_the_old_npz_layout(tmp_path):
+    """A fileset written as ``state.npz`` (bfloat16 widened to float32,
+    a manifest with no layout) by earlier versions still restores."""
+    proj = AcaiProject("p", tmp_path)
+    tree = _mixed_tree()
+    state = {"params": tree}
+    flat = {"/".join(str(getattr(p, "key", p)) for p in path):
+            np.asarray(leaf.astype(jnp.float32) if leaf.dtype == jnp.bfloat16
+                       else leaf)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]}
+    buf = io.BytesIO()
+    np.savez(buf, **flat)
+    paths = ["/old-ckpt/state.npz", "/old-ckpt/manifest.json"]
+    sid = proj.storage.begin_session(paths, creator="trainer")
+    proj.storage.session_put(sid, paths[0], buf.getvalue())
+    proj.storage.session_put(sid, paths[1], json.dumps(
+        {"step": 11, "keys": sorted(flat), "extra": {}}).encode())
+    fvs = proj.storage.commit_session(sid)
+    proj.filesets.create("old-ckpt", [f"{fv.path}@{fv.version}"
+                                      for fv in fvs])
+    restored, step = CheckpointManager(proj, "old").restore(state)
+    assert step == 11
+    _assert_same(restored, state)
+
+
+def test_checkpoint_save_holds_no_second_copy_of_the_state(tmp_path):
+    """A save of a 64 MB state allocates on the host less than 0.3x the
+    state's size beyond the one host copy any save makes (``np.asarray``
+    of each leaf: the whole state on a TPU, nothing on the CPU backend,
+    whose arrays are host memory already): 1.3x on a TPU. A whole-state
+    bytes object (``np.savez`` into memory, ``getvalue``) fails it."""
+    params = {f"w{i}": jnp.full((1 << 20, 2), i, jnp.float32)
+              for i in range(8)}
+    nbytes = sum(a.nbytes for a in params.values())
+    assert nbytes == 64 << 20
+    ckpt = CheckpointManager(AcaiProject("p", tmp_path), "mem")
+    ckpt.save(0, {"warm": jnp.zeros(4)})
+    tracemalloc.start()
+    try:
+        host = [np.asarray(a) for a in params.values()]
+        _, fetched = tracemalloc.get_traced_memory()
+        del host
+        tracemalloc.reset_peak()
+        ckpt.save(1, params)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak < fetched + 0.3 * nbytes, (peak / nbytes, fetched / nbytes)
+    state, _ = ckpt.restore({"params": params})
+    _assert_same(state, {"params": params})
 
 
 def test_supervisor_restart_and_stragglers(tmp_path):
