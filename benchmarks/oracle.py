@@ -3,7 +3,8 @@ experiments (Tables 2/3 analog).
 
 On a real cluster the profiler's training data comes from real runs; this
 container is CPU-only, so the oracle predicts step time from the same
-three-term roofline the dry-run derives, as a function of (chips, hbm_gb):
+three-term roofline of ``roofline/analysis.py``, as a function of
+(chips, hbm_gb):
 
   compute    = MODEL_FLOPS * remat_factor / (chips * PEAK)
   memory     = (3 * param_bytes + act_bytes(batch, seq) ) / (chips * HBM)

@@ -1,10 +1,6 @@
-"""Benchmark harness — one benchmark per paper table (+ kernel µbenches and
-the roofline collation). Prints ``name,us_per_call,derived`` CSV lines per
-the repo contract, then writes a JSON blob with the full results.
-
-NOTE: the dry-run sweep (multi-pod compiles) is NOT run from here — it
-needs 512 placeholder devices (run ``python -m repro.launch.dryrun --all``);
-this harness only COLLATES its JSON artifacts if present.
+"""Benchmark harness — one benchmark per paper table (+ kernel µbenches).
+Prints ``name,us_per_call,derived`` CSV lines per the repo contract, then
+writes a JSON blob with the full results.
 """
 from __future__ import annotations
 
@@ -58,19 +54,6 @@ def main() -> None:
     for row in rk:
         print(f"kernel.{row['kernel']},{row['us_per_call_interpret']:.0f},"
               f"max_err={row['max_err']:.2e}")
-
-    try:
-        from benchmarks import roofline_sweep
-        rows = roofline_sweep.load()
-        if rows:
-            results["roofline_summary"] = roofline_sweep.summary(rows)
-            s = results["roofline_summary"]
-            print(f"roofline.cells,"
-                  f"{(time.perf_counter()-t0)*1e6:.0f},"
-                  f"ok_single={s['cells_ok_single']}"
-                  f"_ok_multi={s['cells_ok_multi']}_na={s['cells_na']}")
-    except Exception as e:  # noqa: BLE001
-        print(f"roofline.collate,0,skipped:{e!r}")
 
     print(f"total.wall,{(time.perf_counter()-t0)*1e6:.0f},seconds="
           f"{time.perf_counter()-t0:.1f}")

@@ -66,8 +66,8 @@ from repro.serve.decode import make_prefill_step  # noqa: E402
 from repro.sharding import rules as SR  # noqa: E402
 from repro.sharding.mesh import make_mesh  # noqa: E402
 from repro.train.checkpoints import CheckpointManager  # noqa: E402
-from repro.train.optimizer import OptimizerConfig  # noqa: E402
-from repro.train.train_step import TrainConfig, make_opt_state  # noqa: E402
+from repro.train.optimizer import OptimizerConfig, init_opt_state  # noqa: E402
+from repro.train.train_step import TrainConfig  # noqa: E402
 
 WORKDIR = ROOT / ".chip_smoke"
 ARCH = "olmo-1b"
@@ -261,12 +261,10 @@ def check_train(cfg, seed: int, workdir: Path) -> None:
           f"{[round(x, 4) for x in losses]}", flush=True)
     if len(losses) != TRAIN_STEPS or not np.isfinite(losses).all():
         raise AssertionError(f"losses {losses}")
-    tcfg = TrainConfig()
     shapes = jax.eval_shape(functools.partial(M.init_params, cfg),
                             jax.random.PRNGKey(seed))
     template = {"params": shapes,
-                "opt": jax.eval_shape(functools.partial(
-                    make_opt_state, tcfg=tcfg), shapes)}
+                "opt": jax.eval_shape(init_opt_state, shapes)}
     restored, step = CheckpointManager(project, run).restore(template)
     checksum = float(_checksum(restored["params"]))
     print(f"  checkpoint restored at step {step}; params checksum "

@@ -17,9 +17,8 @@ from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.models import model as M
 from repro.train.checkpoints import CheckpointManager
 from repro.train.fault import TrainSupervisor
-from repro.train.optimizer import OptimizerConfig
-from repro.train.train_step import (TrainConfig, make_opt_state,
-                                    make_train_step)
+from repro.train.optimizer import OptimizerConfig, init_opt_state
+from repro.train.train_step import TrainConfig, make_train_step
 
 
 def main():
@@ -37,7 +36,7 @@ def main():
     ocfg = OptimizerConfig(lr=3e-3, warmup_steps=5, total_steps=args.steps,
                            weight_decay=0.0)
     step = jax.jit(make_train_step(cfg, tcfg, ocfg))
-    opt = make_opt_state(params, tcfg)
+    opt = init_opt_state(params)
     pipe = TokenPipeline(DataConfig(vocab_size=32, seq_len=32,
                                     global_batch=16, markov_temp=2.5), cfg)
 

@@ -1,6 +1,6 @@
 """Serve a small model with batched requests: prefill via the decode path,
 then greedy generation with the KV-cache/SSM-state machinery — the same
-serve_step the decode dry-run cells lower.
+serve_step ``launch/serve.py`` runs.
 
     PYTHONPATH=src python examples/serve_batch.py [--arch rwkv6-7b]
 """

@@ -28,7 +28,7 @@ def get_shape(name: str) -> ShapeConfig:
 
 def applicable(arch: ArchConfig, shape: ShapeConfig) -> tuple[bool, str]:
     """(runnable?, reason-if-not). long_500k needs sub-quadratic sequence
-    handling -> SSM/hybrid only (see DESIGN.md §6)."""
+    handling -> SSM/hybrid only."""
     if shape.name == "long_500k" and not arch.subquadratic:
         return False, ("pure full-attention arch: 500k-token KV decode is "
                        "quadratic-prefill territory; skipped per assignment")

@@ -1,4 +1,4 @@
-"""Pallas kernel block-size autotuner (ROADMAP item 2, perf_hillclimb idiom).
+"""Pallas kernel block-size autotuner (ROADMAP item 2).
 
 The four seed kernels (flash attention, decode attention, mamba2 SSD,
 RWKV6) all expose block/chunk sizes chosen for the MXU's 128x128 systolic

@@ -1,7 +1,6 @@
 """Batched serving driver: continuous-batching loop over the one-token
-serve step (the same program the decode dry-run cells lower for the
-production meshes). ``serve`` takes any config; ``main`` runs it on the
-reduced one.
+serve step. ``serve`` takes any config; ``main`` runs it on the reduced
+one.
 
     PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b --requests 6
 """

@@ -31,14 +31,14 @@ from repro.sharding.mesh import make_mesh
 from repro.sharding import rules as SR
 from repro.train.checkpoints import CheckpointManager
 from repro.train.fault import SupervisorReport, TrainSupervisor
-from repro.train.optimizer import OptimizerConfig, opt_state_specs
-from repro.train.train_step import (TrainConfig, make_opt_state,
-                                    make_train_step)
+from repro.train.optimizer import (OptimizerConfig, init_opt_state,
+                                   opt_state_specs)
+from repro.train.train_step import TrainConfig, make_train_step
 
 
 def build_sharded_train(cfg, tcfg, ocfg, mesh):
-    """Production assembly: specs + jit with shardings (used on pods; the
-    dry-run lowers exactly this)."""
+    """Production assembly: specs + jit with shardings (used on pods;
+    ``tests/test_tpu_compile.py`` compiles it for a v5e 2x2)."""
     rules = SR.AxisRules.for_mesh(mesh)
     SR.set_rules(rules)
     param_shapes = jax.eval_shape(
@@ -77,8 +77,7 @@ def init_train(cfg, tcfg: TrainConfig, ocfg: OptimizerConfig, *,
                        donate_argnums=(0, 1))
     params = jax.jit(functools.partial(M.init_params, cfg),
                      out_shardings=pshard)(jax.random.PRNGKey(seed))
-    opt = jax.jit(functools.partial(make_opt_state, tcfg=tcfg),
-                  out_shardings=oshard)(params)
+    opt = jax.jit(init_opt_state, out_shardings=oshard)(params)
     return step, params, opt
 
 

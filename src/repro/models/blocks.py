@@ -119,15 +119,14 @@ def _gqa_expand(k, n_heads):
     return jnp.repeat(k, reps, axis=2)
 
 
-def chunked_causal_attention(q, k, v, *, chunk: int = 512,
-                             logit_dtype=jnp.float32):
+def chunked_causal_attention(q, k, v, *, chunk: int = 512):
     """Causal attention in query chunks, O(S*chunk) live memory: chunk i
     takes an exact fp32 softmax over the keys up to its own end, so the
     scores above the diagonal's chunks are never computed (half of S x S
     in all). Differentiated, each chunk's scores are recomputed rather
     than kept. q, k: (B, S, H, D), v: (B, S, H, Dv) (kv already
-    GQA-expanded); the scores are scaled by D^-1/2 and materialize at
-    ``logit_dtype`` (bf16 under §Perf A8)."""
+    GQA-expanded); the scores are scaled by D^-1/2 and materialize in
+    fp32."""
     b, s, h, d = q.shape
     nc = max(s // chunk, 1)
     chunk = s // nc
@@ -136,19 +135,19 @@ def chunked_causal_attention(q, k, v, *, chunk: int = 512,
     @functools.partial(jax.checkpoint, static_argnums=(3,))
     def block(qb, kb, vb, i):
         sc = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
-                        preferred_element_type=logit_dtype)
-        q_pos = i * chunk + jnp.arange(chunk)
+                        preferred_element_type=jnp.float32)
+        q_pos = i * chunk + jnp.arange(qb.shape[1])
         mask = q_pos[:, None] >= jnp.arange(kb.shape[1])[None, :]
-        sc = jnp.where(mask[None, None], sc.astype(jnp.float32) * scale,
-                       -jnp.inf)
+        sc = jnp.where(mask[None, None], sc * scale, -jnp.inf)
         p = jax.nn.softmax(sc, axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vb.dtype), vb,
                           preferred_element_type=jnp.float32).astype(q.dtype)
 
-    end = lambda i: (i + 1) * chunk  # noqa: E731
+    # the last chunk also takes the rows past nc * chunk
+    ends = [(i + 1) * chunk for i in range(nc - 1)] + [s]
     return jnp.concatenate(
-        [block(q[:, i * chunk:end(i)], k[:, :end(i)], v[:, :end(i)], i)
-         for i in range(nc)], axis=1)
+        [block(q[:, i * chunk:e], k[:, :e], v[:, :e], i)
+         for i, e in enumerate(ends)], axis=1)
 
 
 def full_causal_attention(q, k, v):
@@ -197,7 +196,7 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, cache_len):
 
 def attention_block(p, x, cfg: ArchConfig, *, rope=None, positions=None,
                     kv_cache=None, cache_len=None, kv_src=None,
-                    causal=True, attn_impl="xla", seq_axis=None):
+                    causal=True, seq_axis=None):
     """Full attention sub-block: proj -> rope -> (qk-norm) -> attn -> out proj.
 
     kv_cache: None for train/prefill; for decode this layer's (k, v) cache
@@ -240,28 +239,16 @@ def attention_block(p, x, cfg: ArchConfig, *, rope=None, positions=None,
         o = jnp.einsum("bhqk,bkhd->bqhd", pr, vq)
     else:                                 # train / prefill, causal
         o = causal_attention(q, _gqa_expand(k, cfg.n_heads),
-                             _gqa_expand(v, cfg.n_heads), attn_impl)
+                             _gqa_expand(v, cfg.n_heads))
     out = o.reshape(b, s, cfg.n_heads * hd) @ p["wo"].astype(cd)
     return out, new_rows
 
 
-def causal_attention(q, k, v, attn_impl="xla"):
+def causal_attention(q, k, v):
     """Causal self-attention over a whole sequence; q, k, v (B, S, H, D),
-    v's head size may differ from q's and k's (the XLA paths only)."""
-    s = q.shape[1]
-    if attn_impl == "pallas":
-        from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=True)
-    if attn_impl == "pallas-interpret":
-        from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=True, interpret=True)
-    if attn_impl == "xla-bf16-logits" and s > 1024:
-        # §Perf A8: materialize per-chunk score blocks in bf16 (the
-        # online-softmax running stats stay fp32); on TPU the Pallas
-        # kernel keeps scores in VMEM entirely — this is the XLA-path
-        # approximation of that traffic saving
-        return chunked_causal_attention(q, k, v, logit_dtype=jnp.bfloat16)
-    if s <= 1024:
+    v's head size may differ from q's and k's. Full attention up to 1024
+    positions, query chunks above."""
+    if q.shape[1] <= 1024:
         return full_causal_attention(q, k, v)
     return chunked_causal_attention(q, k, v)
 
@@ -286,7 +273,7 @@ def init_mla(cfg: ArchConfig, key):
     }
 
 
-def mla_block(p, x, cfg: ArchConfig, *, rope, attn_impl="xla"):
+def mla_block(p, x, cfg: ArchConfig, *, rope):
     """q = x W_q, split per head into q_nope and q_pe; [c, k_pe] = x W_kva;
     [k_nope, v] = RMSNorm(c) W_kvb per head (eps 1e-6); RoPE on q_pe and
     on k_pe (one head, shared by all); causal softmax over (q_nope.k_nope
@@ -310,7 +297,7 @@ def mla_block(p, x, cfg: ArchConfig, *, rope, attn_impl="xla"):
         k_pe = apply_rope(kva[..., None, r:], cos, sin)        # (B,S,1,R)
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
             k_pe, (b, s, h, k_pe.shape[-1]))], -1)
-        o = causal_attention(q, k, kv[..., nope:], attn_impl)
+        o = causal_attention(q, k, kv[..., nope:])
         return o.reshape(b, s, h * dv) @ p["wo"].astype(cd)
 
 

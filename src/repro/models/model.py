@@ -33,10 +33,9 @@ def init_params(cfg: ArchConfig, key):
 
 
 def make_ctx(cfg: ArchConfig, seq_len: int, mode: str, *,
-             attn_impl: str = "xla", remat: Optional[str] = "full",
-             vision=None, cache_len=None, compute_dtype=jnp.bfloat16) -> dict:
-    ctx = {"mode": mode, "attn_impl": attn_impl, "remat": remat,
-           "compute_dtype": compute_dtype}
+             remat: Optional[str] = "full", vision=None, cache_len=None,
+             compute_dtype=jnp.bfloat16) -> dict:
+    ctx = {"mode": mode, "remat": remat, "compute_dtype": compute_dtype}
     if not cfg.attention_free:
         ctx["rope"] = B.rope_table(seq_len, cfg.rope_dim, cfg.rope_theta)
     if vision is not None:
@@ -101,8 +100,8 @@ def loss_fn(params, batch, cfg: ArchConfig, ctx: dict):
 def prefill(params, tokens, cfg: ArchConfig, ctx: dict):
     """Forward over the prompt; returns last-position logits.
 
-    (Cache export for chained decode lives in serve/decode.py; the dry-run
-    prefill program is logits-only, which matches a scoring/prefill step.)"""
+    (Cache export for chained decode lives in serve/decode.py; the
+    prefill step is logits-only, which matches a scoring/prefill step.)"""
     logits, aux, _ = forward(params, tokens, cfg, ctx)
     return logits[:, -1]
 

@@ -118,15 +118,13 @@ def layer_fwd(block: str, p, x, cfg: ArchConfig, ctx: dict,
         kv_cache = state if decode else None
         new_rows = None
         if cfg.mla:
-            o = B.mla_block(p["attn"], h, cfg, rope=ctx["rope"],
-                            attn_impl=ctx.get("attn_impl", "xla"))
+            o = B.mla_block(p["attn"], h, cfg, rope=ctx["rope"])
         else:
             with jax.named_scope("attention"):
                 o, new_rows = B.attention_block(
                     p["attn"], h, cfg, rope=ctx.get("rope"),
                     positions=ctx.get("positions"),
-                    kv_cache=kv_cache, cache_len=ctx.get("cache_len"),
-                    attn_impl=ctx.get("attn_impl", "xla"))
+                    kv_cache=kv_cache, cache_len=ctx.get("cache_len"))
         x = x + o
         h = B.apply_norm(p["ln2"], x, cfg)
         if block == "moe":

@@ -1,4 +1,4 @@
-"""Roofline analysis from compiled artifacts (EXPERIMENTS.md §Roofline).
+"""Roofline analysis from compiled artifacts.
 
 Three terms per (arch x shape x mesh), all in seconds:
 
@@ -14,7 +14,7 @@ all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute
 reduce-scatter (wire bytes ~ group_size x result bytes).
 
 Hardware constants come from ``DEVICE_PEAKS``, keyed by the device kind
-JAX reports; the dry-run's target chip is the TPU v5e.
+JAX reports; ``TARGET`` is the TPU v5e.
 """
 from __future__ import annotations
 
@@ -70,7 +70,7 @@ def device_peaks(device_kind: str) -> HardwareSpec:
                        f"their source") from None
 
 
-TARGET = DEVICE_PEAKS["TPU v5 lite"]      # the dry-run's chip
+TARGET = DEVICE_PEAKS["TPU v5 lite"]      # the benchmark's chip
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

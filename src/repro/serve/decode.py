@@ -1,5 +1,4 @@
-"""Serving steps: prefill and one-token decode (the dry-run's ``serve_step``
-lowers these)."""
+"""Serving steps: prefill and one-token decode."""
 from __future__ import annotations
 
 import jax
@@ -10,12 +9,10 @@ from repro.models import model as M
 from repro.models import transformer as T
 
 
-def make_prefill_step(cfg: ArchConfig, *, attn_impl: str = "xla",
-                      compute_dtype=jnp.bfloat16):
+def make_prefill_step(cfg: ArchConfig, *, compute_dtype=jnp.bfloat16):
     def prefill_step(params, batch):
         tokens = batch["tokens"]
-        ctx = M.make_ctx(cfg, tokens.shape[1], "prefill",
-                         attn_impl=attn_impl, remat=None,
+        ctx = M.make_ctx(cfg, tokens.shape[1], "prefill", remat=None,
                          vision=batch.get("vision"),
                          compute_dtype=compute_dtype)
         return M.prefill(params, tokens, cfg, ctx)

@@ -246,7 +246,7 @@ def decode_state_specs(cfg, global_batch: int,
 
 def batch_specs(cfg, shape_kind: str, global_batch: int,
                 rules: Optional[AxisRules] = None, layout: str = "fsdp"):
-    """Input-batch PartitionSpecs per shape kind (see launch/dryrun.py)."""
+    """Input-batch PartitionSpecs per shape kind."""
     rules = rules or _ACTIVE
     b = None
     if rules is not None and layout != "resident":

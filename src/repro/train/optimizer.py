@@ -37,17 +37,11 @@ def schedule(cfg: OptimizerConfig, step):
     return cfg.lr * warm * frac
 
 
-def init_opt_state(params, master_weights: bool = False):
+def init_opt_state(params):
     zeros = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
-    state = {"mu": zeros,
-             "nu": jax.tree.map(jnp.zeros_like, zeros),
-             "step": jnp.zeros((), jnp.int32)}
-    if master_weights:
-        # params live in bf16 (collectives/matmuls stream bf16); the fp32
-        # truth lives here, sharded like the moments (ZeRO)
-        state["master"] = jax.tree.map(
-            lambda p: p.astype(jnp.float32), params)
-    return state
+    return {"mu": zeros,
+            "nu": jax.tree.map(jnp.zeros_like, zeros),
+            "step": jnp.zeros((), jnp.int32)}
 
 
 # Leaves AdamW does not update: an MoE router's correction bias picks
@@ -69,9 +63,8 @@ def global_norm(tree) -> jnp.ndarray:
 
 
 def adamw_update(cfg: OptimizerConfig, params, grads, opt_state):
-    """Returns (new_params, new_opt_state, metrics). With a "master" entry
-    in opt_state the update is computed on the fp32 masters and params are
-    re-emitted at their storage dtype (bf16 mixed-precision training)."""
+    """Returns (new_params, new_opt_state, metrics). The update is
+    computed in fp32 and each param is re-emitted at its storage dtype."""
     step = opt_state["step"] + 1
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9))
@@ -79,12 +72,10 @@ def adamw_update(cfg: OptimizerConfig, params, grads, opt_state):
     b1, b2 = cfg.betas
     bc1 = 1 - b1 ** step.astype(jnp.float32)
     bc2 = 1 - b2 ** step.astype(jnp.float32)
-    masters = opt_state.get("master")
-    base = masters if masters is not None else params
 
-    def upd(p, out_dtype, train, g, mu, nu):
+    def upd(p, train, g, mu, nu):
         if not train:
-            return p.astype(out_dtype), p.astype(jnp.float32), mu, nu
+            return p, mu, nu
         g = g.astype(jnp.float32) * scale
         mu = b1 * mu + (1 - b1) * g
         nu = b2 * nu + (1 - b2) * jnp.square(g)
@@ -93,32 +84,27 @@ def adamw_update(cfg: OptimizerConfig, params, grads, opt_state):
         delta = mhat / (jnp.sqrt(nhat) + cfg.eps)
         if p.ndim >= 2:                       # decoupled WD on matrices only
             delta = delta + cfg.weight_decay * p.astype(jnp.float32)
-        new32 = p.astype(jnp.float32) - lr * delta
-        return new32.astype(out_dtype), new32, mu, nu
+        new = p.astype(jnp.float32) - lr * delta
+        return new.astype(p.dtype), mu, nu
 
-    dtypes = jax.tree.map(lambda p: p.dtype, params)
-    out = jax.tree.map(upd, base, dtypes, trainable(params), grads,
+    out = jax.tree.map(upd, params, trainable(params), grads,
                        opt_state["mu"], opt_state["nu"])
     pick = lambda i: jax.tree.map(lambda t: t[i], out,
                                   is_leaf=lambda t: isinstance(t, tuple))
-    new_params = pick(0)
-    new_state = {"mu": pick(2), "nu": pick(3), "step": step}
-    if masters is not None:
-        new_state["master"] = pick(1)
-    return new_params, new_state, {"grad_norm": gnorm, "lr": lr}
+    return (pick(0), {"mu": pick(1), "nu": pick(2), "step": step},
+            {"grad_norm": gnorm, "lr": lr})
 
 
 # ---------------------------------------------------------------------------
 # ZeRO-1 sharding of the optimizer state
 # ---------------------------------------------------------------------------
 
-def opt_state_specs(param_specs, param_shapes, rules=None,
-                    zero: bool = True):
-    """Derive opt-state PartitionSpecs. With ``zero`` and a 'data' axis in
-    the rules, moments get one additional dim sharded over data (ZeRO-1)."""
+def opt_state_specs(param_specs, param_shapes, rules=None):
+    """Derive opt-state PartitionSpecs. With a 'data' axis in the rules,
+    moments get one additional dim sharded over data (ZeRO-1)."""
     from repro.sharding.rules import current_rules
     rules = rules or current_rules()
-    zero_axes = rules.table.get("zero", ()) if (rules and zero) else ()
+    zero_axes = rules.table.get("zero", ()) if rules else ()
     zero_size = 1
     if rules and zero_axes:
         zero_size = int(rules.mesh.shape[zero_axes[0]])

@@ -6,7 +6,7 @@ contiguous block of layers and microbatch activations flow stage-to-stage
 via ``jax.lax.ppermute`` inside ``shard_map``.
 
 ``pipeline_apply`` runs the canonical schedule: with S stages and M
-microbatches, T = M + S - 1 ticks; stage s computes microbatch t-s at tick
+microbatch slices, T = M + S - 1 ticks; stage s computes microbatch t-s at tick
 t; activations hop one stage per tick (bubble fraction (S-1)/T). The layer
 stack must be expressible as S identical-signature stage functions over
 stacked per-stage params — exactly the shape of our scan-over-layers

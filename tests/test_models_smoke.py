@@ -107,3 +107,40 @@ def test_param_count_positive(arch):
     n = cfg.n_params()
     na = cfg.n_active_params()
     assert n > 0 and na > 0 and na <= n
+
+
+def _plain_causal_attention(q, k, v):
+    """Causal softmax attention in float32, every score kept."""
+    hi = jax.lax.Precision.HIGHEST
+    s, d = q.shape[1], q.shape[-1]
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=hi) / np.sqrt(d)
+    sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, axis=-1), v,
+                      precision=hi)
+
+
+@pytest.mark.parametrize("s,dv", [(1024, 16), (1025, 16), (2048, 16),
+                                  (2048, 8)])
+def test_causal_attention_picks_its_core_by_length(s, dv):
+    """Full attention up to 1024 positions, query chunks above (a length
+    the chunk does not divide included); a v head narrower than q's and
+    k's, as latent attention has. Output and gradients match the plain
+    float32 reference."""
+    from repro.models import blocks as B
+
+    keys = jax.random.split(jax.random.PRNGKey(s + dv), 4)
+    q, k = (jax.random.normal(kk, (1, s, 2, 16)) for kk in keys[:2])
+    v = jax.random.normal(keys[2], (1, s, 2, dv))
+    ct = jax.random.normal(keys[3], (1, s, 2, dv))
+    out = B.causal_attention(q, k, v)
+    core = (B.full_causal_attention if s <= 1024
+            else B.chunked_causal_attention)
+    np.testing.assert_array_equal(out, core(q, k, v))
+    np.testing.assert_allclose(out, _plain_causal_attention(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+
+    def grads(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a) * ct), (0, 1, 2))(q, k, v)
+    for a, b in zip(grads(B.causal_attention),
+                    grads(_plain_causal_attention)):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)

@@ -149,8 +149,8 @@ from repro.launch.train import build_sharded_train
 from repro.models import model as M
 from repro.sharding import rules as SR
 from repro.sharding.mesh import make_mesh
-from repro.train.optimizer import OptimizerConfig
-from repro.train.train_step import TrainConfig, make_opt_state, make_train_step
+from repro.train.optimizer import OptimizerConfig, init_opt_state
+from repro.train.train_step import TrainConfig, make_train_step
 
 base = get_arch("moonlight-16b-a3b").reduced()
 cfg = dataclasses.replace(base, moe=dataclasses.replace(
@@ -168,14 +168,14 @@ n_moe = cfg.n_layers - cfg.first_k_dense
 with jax.default_matmul_precision("highest"):
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     one, _, m1 = jax.jit(make_train_step(cfg, tcfg, ocfg))(
-        params, make_opt_state(params, tcfg), batch)
+        params, init_opt_state(params), batch)
     # 2x2: experts over "model"; 4x1: every device holds all four
     for shape in ((2, 2), (4, 1)):
         mesh = make_mesh(shape, ("data", "model"))
         step, pshard, oshard = build_sharded_train(cfg, tcfg, ocfg, mesh)
         fresh = jax.tree.map(jnp.copy, params)       # the step donates it
         two, _, m2 = step(jax.device_put(fresh, pshard),
-                          jax.device_put(make_opt_state(params, tcfg), oshard),
+                          jax.device_put(init_opt_state(params), oshard),
                           batch)
         SR.set_rules(None)
         for k in ("loss", "aux_loss", "grad_norm", "moe/held_rows",

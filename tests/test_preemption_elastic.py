@@ -325,12 +325,15 @@ def test_threadpool_cooperative_preempt_resumes():
     sched = Scheduler(registry, runner, bus, quota_k=10, cluster=cl,
                       preemption=True, starvation_threshold=1e9)
     calls = []
+    waiting = threading.Event()
 
     def fn(workdir, job):
         calls.append(job.epoch)
         if len(calls) == 1:
             hook = preemption_hook(job)
-            assert job.preempt_flag.wait(10.0), "preempt signal never came"
+            flag = job.preempt_flag     # this incarnation's signal
+            waiting.set()
+            assert flag.wait(10.0), "preempt signal never came"
             hook(step=7)            # raises the external JobPreempted
             raise AssertionError("hook should have raised")
         return {"resumed": True}
@@ -342,6 +345,10 @@ def test_threadpool_cooperative_preempt_resumes():
     while registry.get(j.job_id).state != JobState.RUNNING:
         assert time.monotonic() < deadline
         time.sleep(0.005)
+    # preempt the fn while it waits: a preemption before the worker
+    # thread reaches the fn relaunches the job, and the two incarnations
+    # then race for the first call
+    assert waiting.wait(10.0)
     assert sched.preempt(j.job_id)
     deadline = time.monotonic() + 10.0
     while registry.get(j.job_id).state not in (JobState.FINISHED,

@@ -1,7 +1,4 @@
-"""Active-refinement provisioning (beyond-paper) + feature templates +
-master-weights training path."""
-import jax
-import jax.numpy as jnp
+"""Active-refinement provisioning (beyond-paper) + feature templates."""
 
 from repro.core.provision.autoprovision import AutoProvisioner
 from repro.core.provision.features import template_for
@@ -79,33 +76,3 @@ def test_template_for_families():
     assert "kv_shard" in ssm.resource_hints
     assert len(dense.grid()) == 27
 
-
-def test_master_weights_training():
-    from repro.configs.base import get_arch
-    from repro.models import model as M
-    from repro.train.optimizer import OptimizerConfig
-    from repro.train.train_step import (TrainConfig, make_opt_state,
-                                        make_train_step)
-    from repro.data.pipeline import DataConfig, TokenPipeline
-    cfg = get_arch("olmo-1b").reduced()
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    params = jax.tree.map(lambda p: p.astype(jnp.bfloat16)
-                          if p.dtype == jnp.float32 else p, params)
-    tcfg = TrainConfig(master_weights=True)
-    opt = make_opt_state(params, tcfg)
-    assert "master" in opt
-    assert all(m.dtype == jnp.float32 for m in jax.tree.leaves(opt["master"]))
-    step = jax.jit(make_train_step(
-        cfg, tcfg, OptimizerConfig(lr=3e-3, warmup_steps=2,
-                                   weight_decay=0.0)))
-    pipe = TokenPipeline(DataConfig(vocab_size=32, seq_len=32,
-                                    global_batch=16, markov_temp=2.5), cfg)
-    losses = []
-    for i in range(15):
-        batch = jax.tree.map(jnp.asarray, pipe.batch_at(i))
-        params, opt, m = step(params, opt, batch)
-        losses.append(float(m["loss"]))
-    # params stay bf16; masters stay fp32; loss decreases
-    assert all(p.dtype == jnp.bfloat16 for p in jax.tree.leaves(params)
-               if jnp.issubdtype(p.dtype, jnp.floating))
-    assert losses[-1] < losses[0] - 0.5, losses

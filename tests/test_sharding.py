@@ -86,12 +86,20 @@ def test_decode_state_specs_match(arch, shape_name, layout):
     ok, _ = applicable(cfg, shape)
     if not ok:
         pytest.skip("n/a cell")
-    if cfg.family == "vlm":
-        pytest.skip("vlm state init needs vision/params; covered by dryrun")
     rules = _rules("single")
     SR.set_rules(None)
-    state_shapes = jax.eval_shape(functools.partial(
-        T.init_decode_state, cfg, shape.global_batch, shape.seq_len))
+    init = functools.partial(T.init_decode_state, cfg, shape.global_batch,
+                             shape.seq_len)
+    if cfg.family == "vlm":
+        # the cross-attention K/V are computed from the vision input
+        _, params = _param_shapes(arch)
+        vision = jax.ShapeDtypeStruct(
+            (shape.global_batch, cfg.n_vision_tokens, cfg.vision_dim),
+            jnp.bfloat16)
+        state_shapes = jax.eval_shape(
+            lambda v, pp: init(vision=v, params=pp), vision, params)
+    else:
+        state_shapes = jax.eval_shape(init)
     specs = SR.decode_state_specs(cfg, shape.global_batch, rules,
                                   layout=layout)
     mesh = MESHES["single"]
@@ -104,8 +112,6 @@ def test_decode_state_specs_match(arch, shape_name, layout):
             assert sds.shape[dim] % _axis_size(mesh, entry) == 0, \
                 (arch, shape_name, layout, sds.shape, spec)
 
-    if cfg.family == "vlm":
-        pytest.skip("vlm state init needs vision/params; covered by dryrun")
     jax.tree.map(check, state_shapes, specs,
                  is_leaf=lambda x: isinstance(x, P) or hasattr(x, "shape"))
     lay = T.build_layout(cfg)

@@ -1,6 +1,6 @@
 """End-to-end behaviour of the whole system: the paper's workflow (data ->
 jobs -> provenance -> provisioning) wrapped around real JAX training, plus
-the (arch x shape) applicability matrix the dry-run enforces."""
+the (arch x shape) applicability matrix."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -13,8 +13,7 @@ from repro.core.engine.registry import JobSpec
 
 
 def test_cell_matrix():
-    archs = [get_arch(a) for a in list_archs()
-             if not a.endswith("-fused")]           # hillclimb variants out
+    archs = [get_arch(a) for a in list_archs()]
     all_cells = cells(archs)
     assert len(all_cells) == 44                      # 11 archs x 4 shapes
     runnable = [c for c in all_cells if c[2]]
@@ -38,9 +37,8 @@ def test_full_acai_training_workflow(tmp_path):
     from repro.data.pipeline import DataConfig, TokenPipeline
     from repro.models import model as M
     from repro.train.checkpoints import CheckpointManager
-    from repro.train.optimizer import OptimizerConfig
-    from repro.train.train_step import (TrainConfig, make_opt_state,
-                                        make_train_step)
+    from repro.train.optimizer import OptimizerConfig, init_opt_state
+    from repro.train.train_step import TrainConfig, make_train_step
 
     plat = AcaiPlatform(tmp_path)
     admin = plat.create_project(plat.admin_token, "e2e")
@@ -56,7 +54,7 @@ def test_full_acai_training_workflow(tmp_path):
         step = jax.jit(make_train_step(
             cfg, tcfg, OptimizerConfig(lr=lr, warmup_steps=2,
                                        weight_decay=0.0)))
-        opt = make_opt_state(params, tcfg)
+        opt = init_opt_state(params)
         pipe = TokenPipeline(DataConfig(vocab_size=32, seq_len=16,
                                         global_batch=8, markov_temp=2.5),
                              cfg)

@@ -5,8 +5,9 @@ Nothing runs: the TPU compiler refuses here what the chip would refuse
 Pallas kernels at real widths, the olmo-1b serve step at full width and
 depth, the generation cell's serve step writing its donated cache in
 place, the olmo-1b decode step with its cache sharded over the 2x2 mesh,
-the olmo-1b train step sharded over the 2x2 mesh, the MoE cell's train
-step on one chip, and its expert-parallel step over the 2x2 mesh.
+the olmo-1b train and prefill steps sharded over the 2x2 mesh, the MoE
+cell's train step on one chip, and its expert-parallel step over the
+2x2 mesh.
 
 The topology is described inside a module fixture, never at import, so
 only the worker that runs this file loads the TPU compiler.
@@ -183,7 +184,7 @@ def test_sharded_olmo_decode_gathers_no_cache_on_2x2(topo, layout):
 
     params = jax.eval_shape(functools.partial(M.init_params, cfg),
                             jax.random.PRNGKey(0))
-    if resident:        # as the dry run serves it: bfloat16 weights
+    if resident:        # served resident: bfloat16 weights
         params = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16), params)
     states = jax.eval_shape(functools.partial(
@@ -229,8 +230,8 @@ def test_sharded_olmo_train_step_compiles_on_2x2(topo):
     from repro.launch.train import build_sharded_train
     from repro.models import model as M
     from repro.sharding import rules as SR
-    from repro.train.optimizer import OptimizerConfig
-    from repro.train.train_step import TrainConfig, make_opt_state
+    from repro.train.optimizer import OptimizerConfig, init_opt_state
+    from repro.train.train_step import TrainConfig
 
     cfg = dataclasses.replace(get_arch("olmo-1b"), n_layers=4)
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
@@ -241,8 +242,7 @@ def test_sharded_olmo_train_step_compiles_on_2x2(topo):
         step, _, _ = build_sharded_train(cfg, tcfg, ocfg, mesh)
         params = jax.eval_shape(functools.partial(M.init_params, cfg),
                                 jax.random.PRNGKey(0))
-        opt = jax.eval_shape(functools.partial(make_opt_state, tcfg=tcfg),
-                             params)
+        opt = jax.eval_shape(init_opt_state, params)
         tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32)
         compiled = step.lower(params, opt, {"tokens": tokens,
                                             "labels": tokens}).compile()
@@ -254,6 +254,47 @@ def test_sharded_olmo_train_step_compiles_on_2x2(topo):
     # half of the state (the tied embedding shards over "model" only)
     assert mem.argument_size_in_bytes < state_bytes / 2
     assert "all-gather" in compiled.as_text()
+
+
+def test_sharded_prefill_step_compiles_on_2x2(topo):
+    """The reduced olmo-1b's prefill step with FSDP weights and its batch
+    over "data": it compiles for the 2x2 mesh, and the last position's
+    logits stay sharded as the batch is."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.models import model as M
+    from repro.serve.decode import make_prefill_step
+    from repro.sharding import rules as SR
+
+    cfg = get_arch("olmo-1b").reduced()
+    batch_size, seq = 8, 256
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    rules = SR.AxisRules.for_mesh(mesh)
+
+    def named(specs):
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    params = jax.eval_shape(functools.partial(M.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((batch_size, seq), jnp.int32)}
+    SR.set_rules(rules)
+    try:
+        bspecs = SR.batch_specs(cfg, "prefill", batch_size, rules)
+        assert bspecs["tokens"] == P("data", None)
+        out = NamedSharding(mesh, P("data", None))
+        step = jax.jit(
+            make_prefill_step(cfg),
+            in_shardings=(named(SR.param_specs(cfg, rules, fsdp=True,
+                                               param_shapes=params)),
+                          named(bspecs)),
+            out_shardings=out)
+        compiled = step.lower(params, batch).compile()
+    finally:
+        SR.set_rules(None)
+    assert compiled.output_shardings.is_equivalent_to(out, 2)
+    assert "all-gather" in compiled.as_text()       # the FSDP weights
 
 
 def _moonlight(n_layers):
@@ -271,9 +312,8 @@ def test_moe_cell_train_step_fits_one_chip(one_chip):
     forward, and the backward's two products, for gate, up and down),
     and its arguments and temporaries fit the chip."""
     from repro.models import model as M
-    from repro.train.optimizer import OptimizerConfig
-    from repro.train.train_step import (TrainConfig, make_opt_state,
-                                        make_train_step)
+    from repro.train.optimizer import OptimizerConfig, init_opt_state
+    from repro.train.train_step import TrainConfig, make_train_step
 
     cfg = _moonlight(5)
     tcfg = TrainConfig(remat="full")
@@ -286,8 +326,7 @@ def test_moe_cell_train_step_fits_one_chip(one_chip):
 
     params = jax.eval_shape(functools.partial(M.init_params, cfg),
                             jax.random.PRNGKey(0))
-    opt = jax.eval_shape(functools.partial(make_opt_state, tcfg=tcfg),
-                         params)
+    opt = jax.eval_shape(init_opt_state, params)
     tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip)
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         on_chip(params), on_chip(opt),
@@ -309,8 +348,8 @@ def test_expert_parallel_moe_step_compiles_on_2x2(topo):
     from repro.launch.train import build_sharded_train
     from repro.models import model as M
     from repro.sharding import rules as SR
-    from repro.train.optimizer import OptimizerConfig
-    from repro.train.train_step import TrainConfig, make_opt_state
+    from repro.train.optimizer import OptimizerConfig, init_opt_state
+    from repro.train.train_step import TrainConfig
 
     cfg = _moonlight(2)
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
@@ -322,8 +361,7 @@ def test_expert_parallel_moe_step_compiles_on_2x2(topo):
         step, _, _ = build_sharded_train(cfg, tcfg, ocfg, mesh)
         params = jax.eval_shape(functools.partial(M.init_params, cfg),
                                 jax.random.PRNGKey(0))
-        opt = jax.eval_shape(functools.partial(make_opt_state, tcfg=tcfg),
-                             params)
+        opt = jax.eval_shape(init_opt_state, params)
         tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
         compiled = step.lower(params, opt, {"tokens": tokens,
                                             "labels": tokens}).compile()
